@@ -21,6 +21,7 @@ from repro.data.graph500 import graph500_triples
 from repro.db.batching import batch_triples
 from repro.db.kvstore import ShardedTable, shard_of
 from repro.db.naive import NaiveTable
+from repro.compile_cache import enable_compile_cache
 from repro.core.dictionary import StringDict
 from repro.kernels.common import I32_MAX
 from repro.obs import Histogram, default_registry
@@ -559,6 +560,7 @@ def zipf_skew_advisory(s: float, entries_per_shard: int = 1 << 14,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small fast engine A/B + JSON artifact (CI mode)")

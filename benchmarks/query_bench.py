@@ -22,6 +22,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.data.graph500 import graph500_triples
 from repro.db import EdgeSchema, NaiveTable, dbsetup
 from repro.db.kvstore import ShardedTable
@@ -300,8 +301,9 @@ def colsel_read_compare(reps: int = 30, lengths=(64, 256, 1024),
     return result
 
 
-if __name__ == "__main__":
+def main() -> None:
     import argparse
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fused-compare", action="store_true",
                     help="point-read A/B (BENCH_query.json artifact)")
@@ -328,3 +330,7 @@ if __name__ == "__main__":
         print(f"wrote {args.out}")
     else:
         fig4()
+
+
+if __name__ == "__main__":
+    main()

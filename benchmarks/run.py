@@ -22,6 +22,8 @@ import traceback
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
+
 ROWS = []
 
 
@@ -194,6 +196,7 @@ def bench_roofline_summary(full: bool) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None)

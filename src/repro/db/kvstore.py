@@ -155,8 +155,7 @@ def tablet_insert(t: Tablet, br, bc, bv, combiner: str = "last",
     Returns the new tablet; ``new.n`` may exceed capacity — the host MUST
     check for overflow (Accumulo back-pressure analogue).
     """
-    order = jnp.lexsort((bc, br))
-    br, bc, bv = br[order], bc[order], bv[order]
+    br, bc, bv = jax.lax.sort((br, bc, bv), num_keys=2, is_stable=True)
     if use_pallas:
         mr, mc, mv = merge_sorted(t.rows, t.cols, t.vals, br, bc, bv,
                                   interpret=INTERPRET)

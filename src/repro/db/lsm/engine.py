@@ -105,8 +105,7 @@ def _sort_dedup(r, c, v, combiner: str):
     from ..kvstore import _dedup_combine  # shared with the legacy engine
 
     cap = r.shape[0]
-    order = jnp.lexsort((c, r))
-    sr, sc, sv = r[order], c[order], v[order]
+    sr, sc, sv = jax.lax.sort((r, c, v), num_keys=2, is_stable=True)
     keep, out_v = _dedup_combine(sr, sc, sv, combiner)
     pos = jnp.cumsum(keep) - 1
     idx = jnp.where(keep, pos, cap)

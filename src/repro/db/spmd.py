@@ -28,9 +28,6 @@ from ..kernels.common import I32_MAX
 from ..obs import default_registry, merge_snapshots
 from .kvstore import Tablet, shard_of_dev, tablet_insert
 
-from ..compat import SHARD_MAP_KW as _SHARD_MAP_KW
-from ..compat import shard_map as _shard_map
-
 
 def _instrumented(fn, op: str):
     """Host-side step instrumentation: per-process step counters + dispatch
@@ -111,10 +108,10 @@ def make_spmd_ingest_step(mesh, axis: str, num_shards: int, id_capacity: int,
 
     spec_t = Tablet(rows=P(axis, None), cols=P(axis, None),
                     vals=P(axis, None), n=P(axis))
-    fn = _shard_map(shard_fn, mesh=mesh,
-                    in_specs=(spec_t, P(axis, None), P(axis, None),
-                              P(axis, None)),
-                    out_specs=spec_t, **_SHARD_MAP_KW)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(spec_t, P(axis, None), P(axis, None),
+                                 P(axis, None)),
+                       out_specs=spec_t, check_vma=False)
     return _instrumented(jax.jit(fn), "spmd_ingest")
 
 
@@ -176,8 +173,7 @@ def make_spmd_lsm_ingest_step(mesh, axis: str, num_shards: int,
         rr = jax.lax.all_to_all(send[0], axis, 0, 0).reshape(-1)
         rc = jax.lax.all_to_all(send[1], axis, 0, 0).reshape(-1)
         rv = jax.lax.all_to_all(send[2], axis, 0, 0).reshape(-1)
-        order = jnp.lexsort((rc, rr))
-        sr, sc, sv = rr[order], rc[order], rv[order]
+        sr, sc, sv = jax.lax.sort((rr, rc, rv), num_keys=2, is_stable=True)
         keep, out_v = _dedup_combine(sr, sc, sv, combiner)
         cap = sr.shape[0]
         pos = jnp.cumsum(keep) - 1
@@ -194,10 +190,10 @@ def make_spmd_lsm_ingest_step(mesh, axis: str, num_shards: int,
                       k=jnp.minimum(me.k + 1, slots))
         return jax.tree.map(lambda x: x[None], new)
 
-    fn = _shard_map(shard_fn, mesh=mesh,
-                    in_specs=(_l0_spec(axis), P(axis, None), P(axis, None),
-                              P(axis, None)),
-                    out_specs=_l0_spec(axis), **_SHARD_MAP_KW)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(_l0_spec(axis), P(axis, None), P(axis, None),
+                                 P(axis, None)),
+                       out_specs=_l0_spec(axis), check_vma=False)
     return _instrumented(jax.jit(fn), "spmd_lsm_ingest")
 
 
@@ -240,8 +236,7 @@ def make_spmd_tablet_ingest_step(mesh, axis: str, num_shards: int,
         rr = jax.lax.all_to_all(send[0], axis, 0, 0).reshape(-1)
         rc = jax.lax.all_to_all(send[1], axis, 0, 0).reshape(-1)
         rv = jax.lax.all_to_all(send[2], axis, 0, 0).reshape(-1)
-        order = jnp.lexsort((rc, rr))
-        sr, sc, sv = rr[order], rc[order], rv[order]
+        sr, sc, sv = jax.lax.sort((rr, rc, rv), num_keys=2, is_stable=True)
         keep, out_v = _dedup_combine(sr, sc, sv, combiner)
         cap = sr.shape[0]
         pos = jnp.cumsum(keep) - 1
@@ -256,10 +251,10 @@ def make_spmd_tablet_ingest_step(mesh, axis: str, num_shards: int,
                       k=jnp.minimum(me.k + 1, slots))
         return jax.tree.map(lambda x: x[None], new)
 
-    fn = _shard_map(shard_fn, mesh=mesh,
-                    in_specs=(_l0_spec(axis), P(axis, None), P(axis, None),
-                              P(axis, None), P(), P()),
-                    out_specs=_l0_spec(axis), **_SHARD_MAP_KW)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(_l0_spec(axis), P(axis, None), P(axis, None),
+                                 P(axis, None), P(), P()),
+                       out_specs=_l0_spec(axis), check_vma=False)
     return _instrumented(jax.jit(fn), "spmd_tablet_ingest")
 
 
@@ -284,8 +279,7 @@ def make_spmd_lsm_pair_ingest_step(mesh, axis: str, num_shards: int,
         rr = jax.lax.all_to_all(send[0], axis, 0, 0).reshape(-1)
         rc = jax.lax.all_to_all(send[1], axis, 0, 0).reshape(-1)
         rv = jax.lax.all_to_all(send[2], axis, 0, 0).reshape(-1)
-        order = jnp.lexsort((rc, rr))
-        sr, sc, sv = rr[order], rc[order], rv[order]
+        sr, sc, sv = jax.lax.sort((rr, rc, rv), num_keys=2, is_stable=True)
         keep, out_v = _dedup_combine(sr, sc, sv, combiner)
         cap = sr.shape[0]
         pos = jnp.cumsum(keep) - 1
@@ -311,11 +305,11 @@ def make_spmd_lsm_pair_ingest_step(mesh, axis: str, num_shards: int,
         return (jax.tree.map(lambda x: x[None], append(me, fwd)),
                 jax.tree.map(lambda x: x[None], append(met, twd)))
 
-    fn = _shard_map(shard_fn, mesh=mesh,
-                    in_specs=(_l0_spec(axis), _l0_spec(axis), P(axis, None),
-                              P(axis, None), P(axis, None)),
-                    out_specs=(_l0_spec(axis), _l0_spec(axis)),
-                    **_SHARD_MAP_KW)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(_l0_spec(axis), _l0_spec(axis), P(axis, None),
+                                 P(axis, None), P(axis, None)),
+                       out_specs=(_l0_spec(axis), _l0_spec(axis)),
+                       check_vma=False)
     return _instrumented(jax.jit(fn), "spmd_lsm_pair_ingest")
 
 
@@ -380,14 +374,14 @@ def make_spmd_lsm_query_step(mesh, axis: str, combiner: str = "last",
         )(col_s, val_s)
         return (col_s[None], jnp.where(keep, out_v, 0.0)[None], keep[None])
 
-    fn = _shard_map(shard_fn, mesh=mesh,
-                    in_specs=(_l0_spec(axis), Tablet(rows=P(axis, None),
-                                                     cols=P(axis, None),
-                                                     vals=P(axis, None),
-                                                     n=P(axis)),
-                              P(axis, None)),
-                    out_specs=(P(axis, None, None), P(axis, None, None),
-                               P(axis, None, None)), **_SHARD_MAP_KW)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(_l0_spec(axis), Tablet(rows=P(axis, None),
+                                                        cols=P(axis, None),
+                                                        vals=P(axis, None),
+                                                        n=P(axis)),
+                                 P(axis, None)),
+                       out_specs=(P(axis, None, None), P(axis, None, None),
+                                  P(axis, None, None)), check_vma=False)
     base = jax.jit(fn)
     if q_tile is None:
         return _instrumented(base, "spmd_lsm_query")
@@ -475,10 +469,10 @@ def make_spmd_lsm_scan_step(mesh, axis: str, combiner: str = "last",
 
     spec_t = Tablet(rows=P(axis, None), cols=P(axis, None),
                     vals=P(axis, None), n=P(axis))
-    fn = _shard_map(shard_fn, mesh=mesh,
-                    in_specs=(_l0_spec(axis), spec_t, P(axis, None)),
-                    out_specs=(P(axis, None), P(axis, None), P(axis, None),
-                               P(axis, None), P(axis)), **_SHARD_MAP_KW)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(_l0_spec(axis), spec_t, P(axis, None)),
+                       out_specs=(P(axis, None), P(axis, None), P(axis, None),
+                                  P(axis, None), P(axis)), check_vma=False)
     return _instrumented(jax.jit(fn), "spmd_lsm_scan")
 
 
@@ -517,7 +511,7 @@ def make_spmd_lsm_compact_step(mesh, axis: str, combiner: str = "last",
 
     spec_t = Tablet(rows=P(axis, None), cols=P(axis, None),
                     vals=P(axis, None), n=P(axis))
-    fn = _shard_map(shard_fn, mesh=mesh,
-                    in_specs=(_l0_spec(axis), spec_t),
-                    out_specs=(_l0_spec(axis), spec_t), **_SHARD_MAP_KW)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(_l0_spec(axis), spec_t),
+                       out_specs=(_l0_spec(axis), spec_t), check_vma=False)
     return _instrumented(jax.jit(fn), "spmd_lsm_compact")

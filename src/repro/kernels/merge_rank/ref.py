@@ -1,15 +1,15 @@
 """Pure-jnp oracle: stable merge of two (row, col, val) sorted runs."""
+import jax
 import jax.numpy as jnp
 
 
 def merge_sorted_ref(ar, ac, av, br, bc, bv):
-    """Concatenate + stable lexicographic sort (A entries precede ties)."""
-    r = jnp.concatenate([ar, br])
-    c = jnp.concatenate([ac, bc])
-    v = jnp.concatenate([av, bv])
-    side = jnp.concatenate([jnp.zeros_like(ar), jnp.ones_like(br)])
-    order = jnp.lexsort((side, c, r))
-    return r[order], c[order], v[order]
+    """Concatenate + stable lexicographic sort (A entries precede ties, and
+    equal keys within a run keep their order: ``jnp.lexsort`` is unstable
+    on the TPU)."""
+    cat = (jnp.concatenate([ar, br]), jnp.concatenate([ac, bc]),
+           jnp.concatenate([av, bv]))
+    return tuple(jax.lax.sort(cat, num_keys=2, is_stable=True))
 
 
 def row_rank_ref(keys):

@@ -58,9 +58,9 @@ def _rank_batched_kernel(q_ref, tab_ref, o_ref, *, strict: bool):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     q = q_ref[...]        # (bq, 1) int32 — shared across runs
-    t = tab_ref[...]      # (1, bt) int32 — one tile of run k
+    t = tab_ref[0]        # (1, bt) int32 — one tile of run k
     cmp = (t < q) if strict else (t <= q)
-    o_ref[...] += jnp.sum(cmp.astype(jnp.int32), axis=1)[None, :]
+    o_ref[0] += jnp.sum(cmp.astype(jnp.int32), axis=1, keepdims=True)
 
 
 def rank_pallas_batched(tabs: jax.Array, q: jax.Array, *, strict: bool,
@@ -71,13 +71,16 @@ def rank_pallas_batched(tabs: jax.Array, q: jax.Array, *, strict: bool,
 
     Grid = (runs, query_blocks, table_tiles); the table tile axis stays the
     innermost (sequential) dimension so each (run, query-block) output block
-    accumulates in place, exactly like the single-run kernel.
+    accumulates in place, exactly like the single-run kernel. The run axis
+    is a leading array dimension of its own, so every block's last two
+    dims are the single-run kernel's ((1, block_t) table tile, (block_q, 1)
+    ranks) and meet the TPU (8, 128) tiling rule.
 
-    tabs: (K, N) int32, each row sorted, padded with I32_MAX.
+    tabs: (K, 1, N) int32, each run sorted, padded with I32_MAX.
     q:    (Q, 1) int32.
-    Returns (K, Q) int32 ranks.
+    Returns (K, Q, 1) int32 ranks.
     """
-    n_k, n_t = tabs.shape
+    n_k, _, n_t = tabs.shape
     n_q = q.shape[0]
     grid = (n_k, n_q // block_q, n_t // block_t)
     return pl.pallas_call(
@@ -85,9 +88,9 @@ def rank_pallas_batched(tabs: jax.Array, q: jax.Array, *, strict: bool,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_q, 1), lambda k, i, j: (i, 0)),
-            pl.BlockSpec((1, block_t), lambda k, i, j: (k, j)),
+            pl.BlockSpec((1, 1, block_t), lambda k, i, j: (k, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, block_q), lambda k, i, j: (k, i)),
-        out_shape=jax.ShapeDtypeStruct((n_k, n_q), jnp.int32),
+        out_specs=pl.BlockSpec((1, block_q, 1), lambda k, i, j: (k, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_k, n_q, 1), jnp.int32),
         interpret=interpret,
     )(q, tabs)

@@ -35,11 +35,11 @@ def sorted_search_batched(tabs: jax.Array, q: jax.Array, side: str = "left",
     search. Returns int32[K, Q].
     """
     q2, n_q = pad_to(q.astype(jnp.int32).reshape(-1, 1), block_q, 0, 0)
-    tabs2, _ = pad_to(tabs.astype(jnp.int32), block_t, 1, I32_MAX)
+    tabs2, _ = pad_to(tabs.astype(jnp.int32)[:, None, :], block_t, 2, I32_MAX)
     out = rank_pallas_batched(tabs2, q2, strict=(side == "left"),
                               block_q=block_q, block_t=block_t,
                               interpret=interpret)
-    return out[:, :n_q]
+    return out[:, :n_q, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_t",

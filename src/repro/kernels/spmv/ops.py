@@ -29,9 +29,9 @@ def spmv_ell(cols: jax.Array, vals: jax.Array, x: jax.Array,
              interpret: bool = INTERPRET) -> jax.Array:
     """y = A @ x for blocked-ELL A; pad columns are -1."""
     n_r = cols.shape[0]
-    cols_p, _ = pad_to(cols.astype(jnp.int32), block_r, 0, -1)
-    vals_p, _ = pad_to(vals.astype(jnp.float32), block_r, 0, 0.0)
-    x_p, _ = pad_to(x.astype(jnp.float32).reshape(1, -1), block_c, 1, 0.0)
+    cols_p, _ = pad_to(cols.astype(jnp.int32).T, block_r, 1, -1)
+    vals_p, _ = pad_to(vals.astype(jnp.float32).T, block_r, 1, 0.0)
+    x_p, _ = pad_to(x.astype(jnp.float32).reshape(-1, 1), block_c, 0, 0.0)
     out = spmv_ell_pallas(cols_p, vals_p, x_p, block_r=block_r,
                           block_c=block_c, interpret=interpret)
-    return out[:n_r, 0]
+    return out[0, :n_r]

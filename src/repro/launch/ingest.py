@@ -27,8 +27,8 @@ def dryrun(multi_pod: bool, capacity: int = 1 << 20, batch_cap: int = 1 << 15):
     mesh = make_production_mesh(multi_pod=multi_pod)
     s = mesh.shape["data"] * (mesh.shape.get("pod", 1))
     # ingest axis = flattened (pod, data): one ingestor per data shard
-    from ..compat import make_mesh_auto
-    flat = make_mesh_auto((s,), ("data",), devices=jax.devices()[:s])
+    flat = jax.make_mesh((s,), ("data",), devices=jax.devices()[:s],
+                         axis_types=(jax.sharding.AxisType.Auto,))
     # unwrap the host-side metrics wrapper: AOT lowering wants the raw
     # jitted step (tracing through the wrapper would count trace-time)
     step = make_spmd_ingest_step(flat, "data", s, id_capacity=1 << 22)

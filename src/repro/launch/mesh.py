@@ -18,8 +18,8 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, found {len(devices)} — run "
             "under XLA_FLAGS=--xla_force_host_platform_device_count=512")
-    from ..compat import make_mesh_auto
-    return make_mesh_auto(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def batch_axes(multi_pod: bool):

@@ -233,12 +233,11 @@ def _apply_moe_spmd(cfg: ModelConfig, p: Dict, x: jax.Array, sh, rules, mesh
         w_specs = (P_(ep, f_ax, None), P_(ep, f_ax, None), P_(ep, None, f_ax))
     else:         # XLA gathers the fsdp dim (bf16) at the shard_map boundary
         w_specs = (P_(ep, None, None),) * 3
-    from ..compat import SHARD_MAP_KW, shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P_(batch_axes, ep, None), P_(None, None)) + w_specs,
         out_specs=(P_(batch_axes, ep, None), P_()),
-        **SHARD_MAP_KW)
+        check_vma=False)
     y, aux = fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     y = sh(y, "batch", "seq", "model_dim_act")
     if cfg.n_shared_experts:

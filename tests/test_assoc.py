@@ -1,7 +1,8 @@
 """Unit + property tests for associative arrays (paper §II semantics)."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import Assoc, split_str
 

@@ -2,7 +2,8 @@
 combiners, overflow back-pressure, naive-baseline equivalence."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import Assoc
 from repro.data.graph500 import graph500_triples

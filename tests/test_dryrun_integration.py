@@ -31,6 +31,7 @@ print("DRYRUN-OK")
 def test_dryrun_cell_both_meshes():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"  # never reach for a chip the parent holds
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                          capture_output=True, text=True, timeout=900)
@@ -42,6 +43,7 @@ def test_dryrun_cell_both_meshes():
 def test_ingest_dryrun_single_pod():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"  # never reach for a chip the parent holds
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.ingest", "--dryrun",

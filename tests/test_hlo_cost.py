@@ -11,7 +11,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from repro.launch.hlo_cost import HloCostModel
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import make_mesh_auto
 
 # 1) scan of 10 dots == exactly 10 dots of flops
 a = jax.ShapeDtypeStruct((512, 512), jnp.bfloat16)
@@ -36,7 +35,8 @@ c2 = HloCostModel(jax.jit(g2).lower(a).compile().as_text()).cost()
 assert abs(c2.flops - 3 * want) / (3 * want) < 0.01, c2.flops
 
 # 3) sharded matmul: per-device flops + all-reduce detected with ring cost
-mesh = make_mesh_auto((4, 2), ("data", "model"), devices=jax.devices())
+mesh = jax.make_mesh((4, 2), ("data", "model"), devices=jax.devices(),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 w1 = jax.ShapeDtypeStruct((256, 512), jnp.float32)
 w2 = jax.ShapeDtypeStruct((512, 256), jnp.float32)
 x = jax.ShapeDtypeStruct((64, 256), jnp.float32)
@@ -59,6 +59,7 @@ print("HLO-COST-OK")
 def test_hlo_cost_model_validates():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"  # never reach for a chip the parent holds
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                          capture_output=True, text=True, timeout=600)

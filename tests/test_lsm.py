@@ -152,7 +152,8 @@ def test_lsm_overflow_backpressure():
 
 
 # --------------------------------------------------------- k-way merge op
-def test_kway_merge_matches_ref():
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_kway_merge_matches_ref(use_pallas):
     rng = np.random.default_rng(9)
     runs = []
     for n, cap in [(100, 128), (50, 256), (200, 256), (10, 64), (77, 128)]:
@@ -165,9 +166,10 @@ def test_kway_merge_matches_ref():
         r[:n], c[:n] = rr[order], cc[order]
         v[:n] = rng.normal(size=n)
         runs.append((r, c, v))
-    # Pallas path (interpret on CPU) vs pairwise-reduced jnp reference
+    # Pallas path (interpret on CPU) or the one-sort XLA path vs the
+    # pairwise-reduced jnp reference
     mr, mc, mv = kway_merge([tuple(map(np.asarray, run)) for run in runs],
-                            use_pallas=True, interpret=True)
+                            use_pallas=use_pallas, interpret=True)
     er, ec, ev = runs[0]
     for run in runs[1:]:
         er, ec, ev = merge_sorted_ref(er, ec, ev, *run)
@@ -445,6 +447,7 @@ print("LSM-SPMD-OK", len(got))
 def test_spmd_lsm_ingest_and_compact():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"  # never reach for a chip the parent holds
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", SPMD_SCRIPT], env=env,
                          cwd=".", capture_output=True, text=True, timeout=600)

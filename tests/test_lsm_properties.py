@@ -5,10 +5,9 @@ THREE readers of the same logical table — the LSM engine's fused
 single-dispatch read path, its per-run baseline path, and the legacy
 single-run engine — plus a sequential dict oracle; all four must agree
 for every combiner. Range scans are additionally checked against id-list
-point expansion of the same range (the pre-scan read path). Runs under
-real hypothesis when installed, else the deterministic shim
-(tests/_hypothesis_compat.py). ``FUZZ_BUDGET`` (env, CI's weekly deep
-lane) adds that many extra examples per property.
+point expansion of the same range (the pre-scan read path).
+``FUZZ_BUDGET`` (env, CI's weekly deep lane) adds that many extra
+examples per property.
 
 Also home to the fused read paths' structural guarantees: the
 one-dispatch assertions for point queries AND range scans (memtable + L0
@@ -20,7 +19,8 @@ import os
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.db.kvstore import COMBINERS, ShardedTable, shard_of
 from repro.db.lsm import engine as lsm_engine

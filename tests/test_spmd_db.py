@@ -123,6 +123,7 @@ print("SPMD-OK", len(got[0]))
 def test_spmd_ingest_matches_local_driver():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"  # never reach for a chip the parent holds
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env, cwd=".",
                          capture_output=True, text=True, timeout=600)
@@ -219,6 +220,7 @@ def test_spmd_pair_ingest_and_transpose_scan_match_local_engine():
     pair store (``scan_col_range``)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"  # never reach for a chip the parent holds
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", PAIR_SCRIPT], env=env,
                          cwd=".", capture_output=True, text=True,

@@ -20,7 +20,8 @@ import os
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.db.connector import (ReadPlan, TablePair, TransposedView,
                                 dbsetup, delete, recover_connector)
