@@ -290,16 +290,20 @@ class DBserver:
 
     # ----------------------------------------------------- key resolution
     def encode_keys(self, strs: np.ndarray) -> np.ndarray:
-        before = len(self.keydict)
-        ids = self.keydict.encode(strs)
-        if ids.size and ids.max() >= self.id_capacity:
-            raise OverflowError("key universe exceeded id_capacity")
-        if self._keydict_journal is not None and len(self.keydict) > before:
-            # journal newly interned strings (in id order) BEFORE any
-            # triple using those ids can reach a table WAL
-            self._keydict_journal.append(self.keydict._to_str[before:])
-        self._sorted_keys = None  # invalidate range-query snapshot
-        return ids
+        with obs_span("dict.encode", n=len(strs)):
+            before = len(self.keydict)
+            ids = self.keydict.encode(strs)
+            if ids.size and ids.max() >= self.id_capacity:
+                raise OverflowError("key universe exceeded id_capacity")
+            if (self._keydict_journal is not None
+                    and len(self.keydict) > before):
+                # journal newly interned strings (in id order) BEFORE any
+                # triple using those ids can reach a table WAL
+                with obs_span("dict.journal"):
+                    self._keydict_journal.append(
+                        self.keydict._to_str[before:])
+            self._sorted_keys = None  # invalidate range-query snapshot
+            return ids
 
     def checkpoint_keydict(self) -> None:
         """Snapshot the shared key dictionary + reset its journal."""
@@ -660,8 +664,9 @@ class Table:
         rows = np.asarray(rows, dtype=object)
         cols = np.asarray(cols, dtype=object)
         vals = np.asarray(vals)
-        # connector-level root span: every batch (dict encode, WAL append,
-        # memtable insert, any flush/compaction) shares ONE trace id
+        # connector-level span (the root unless EdgeSchema.put_triple
+        # opened one): every batch (dict encode, WAL append, memtable
+        # insert, any flush/compaction) shares ONE trace id
         with obs_span("connector.put", table=self.name, n=len(rows)):
             self._put_triple_batches(rows, cols, vals)
 
@@ -677,11 +682,14 @@ class Table:
                         self._valdict_journal = _DictJournal(
                             self.store._wal_dir, "valdict")
                 before = len(self.valdict)
-                val = self.valdict.encode(bv.astype(object)).astype(np.float32) + 1.0
+                with obs_span("dict.encode", n=len(bv)):
+                    val = self.valdict.encode(
+                        bv.astype(object)).astype(np.float32) + 1.0
                 if (self._valdict_journal is not None
                         and len(self.valdict) > before):
-                    self._valdict_journal.append(
-                        self.valdict._to_str[before:])
+                    with obs_span("dict.journal"):
+                        self._valdict_journal.append(
+                            self.valdict._to_str[before:])
             else:
                 val = bv.astype(np.float32)
             self.store.insert(rid, cid, val)
@@ -692,21 +700,27 @@ class Table:
     def _assemble(self, rid, cid, val) -> Assoc:
         if len(rid) == 0:
             return Assoc()
-        rows = self.server.keydict.decode(rid)
-        cols = self.server.keydict.decode(cid)
-        if self.valdict is not None:
-            vals = self.valdict.decode(val.astype(np.int64) - 1)
-        else:
-            vals = val.astype(np.float64)
-        return Assoc(rows, cols, vals)
+        with obs_span("dict.decode", n=len(rid)):
+            rows = self.server.keydict.decode(rid)
+            cols = self.server.keydict.decode(cid)
+            if self.valdict is not None:
+                vals = self.valdict.decode(val.astype(np.int64) - 1)
+            else:
+                vals = val.astype(np.float64)
+        with obs_span("assoc.build", n=len(rid)):
+            return Assoc(rows, cols, vals)
 
     def __getitem__(self, key) -> Assoc:
         self._check_live()
         rsel, csel = key
-        rplan = self.server.resolve_selector_plan(rsel, axis="row")
-        cplan = self.server.resolve_selector_plan(csel, axis="col")
-        r, c, v = self._execute(rplan, cplan)
-        return self._assemble(r, c, v)
+        # query root: planning, the store read, decode and assembly share
+        # one trace id
+        with obs_span("connector.query", table=self.name):
+            with obs_span("connector.plan"):
+                rplan = self.server.resolve_selector_plan(rsel, axis="row")
+                cplan = self.server.resolve_selector_plan(csel, axis="col")
+            r, c, v = self._execute(rplan, cplan)
+            return self._assemble(r, c, v)
 
     def _execute(self, rplan: ReadPlan, cplan: ReadPlan):
         with obs_span("connector.read", table=self.name,

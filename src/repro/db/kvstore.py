@@ -678,8 +678,8 @@ class ShardedTable:
             return
         if n > self.mem_cap:
             raise OverflowError(f"batch {n} exceeds memtable {self.mem_cap}")
-        t0 = perf_counter()
-        with self._trace.span("ingest", table=self.name, n=n):
+        with self._trace.span("ingest", self._h_ingest, table=self.name,
+                              n=n):
             if _log and self._wal is not None:
                 pair = self.t_store is not None
                 if self.tablet_map is None:
@@ -699,7 +699,6 @@ class ShardedTable:
             self._insert_batch(rows, cols, vals)
             if self.t_store is not None:
                 self.t_store._insert_batch(cols, rows, vals)
-        self._h_ingest.observe(perf_counter() - t0)
 
     def _insert_batch(self, rows, cols, vals):
         n = len(rows)
@@ -773,8 +772,8 @@ class ShardedTable:
         if self.engine == "lsm":
             self._runs.flush_memtable(self._mem_r, self._mem_c, self._mem_v)
         else:
-            t0 = perf_counter()
-            with self._trace.span("flush", table=self.name):
+            with self._trace.span("flush", self._h_flush_single,
+                                  table=self.name):
                 new = self._insert(self.tablets, self._mem_r, self._mem_c,
                                    self._mem_v)
                 if int(new.n.max()) > self.cap:
@@ -783,7 +782,6 @@ class ShardedTable:
                         f"{int(new.n.max())} > {self.cap}")
                 self.tablets = new
             self._shard_views.clear()
-            self._h_flush_single.observe(perf_counter() - t0)
             self._ctr_single["flushes"].inc()
             for s in np.nonzero(self._mem_n)[0]:
                 self._c_shard_flush_single[s].inc()

@@ -42,7 +42,6 @@ shard compacts its peers early — harmless, entries just move down a level).
 from __future__ import annotations
 
 import functools
-from time import perf_counter
 from typing import List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -120,14 +119,15 @@ def _sort_dedup(r, c, v, combiner: str):
 @functools.lru_cache(maxsize=None)
 def _flush_fn(combiner: str, n_words: int, block: int, n_hashes: int):
     """jit(vmap): memtable [S, m] -> one sorted+deduped L0 run per shard,
-    with bloom + fence metadata. Cost O(m log m) per shard."""
+    with bloom + fence metadata. Cost O(m log m) per shard. The inner
+    function's name is the program's name in a profiler trace."""
 
-    def one(r, c, v):
+    def lsm_flush(r, c, v):
         rr, cc, vv, n = _sort_dedup(r, c, v, combiner)
         return (rr, cc, vv, n, bloom_build(rr, n_words, n_hashes),
                 fence_build(rr, block), rr[0], rr[jnp.maximum(n - 1, 0)])
 
-    return jax.jit(jax.vmap(one))
+    return jax.jit(jax.vmap(lsm_flush))
 
 
 @functools.lru_cache(maxsize=None)
@@ -166,9 +166,10 @@ def _compact_fn(combiner: str, use_pallas: bool, out_cap: int, n_words: int,
     Inputs per shard: l0 [K0, m] plus a tuple of level runs ordered
     DEEPEST FIRST (deepest = oldest). kway_merge keeps age order within
     equal-key groups, so one dedup pass applies the combiner exactly.
+    The program is named ``lsm_compact`` in a profiler trace.
     """
 
-    def one(l0_r, l0_c, l0_v, lvls):
+    def lsm_compact(l0_r, l0_c, l0_v, lvls):
         runs = [lv for lv in lvls]
         runs += [(l0_r[k], l0_c[k], l0_v[k]) for k in range(l0_r.shape[0])]
         mr, mc, mv = kway_merge(runs, use_pallas=use_pallas,
@@ -184,7 +185,7 @@ def _compact_fn(combiner: str, use_pallas: bool, out_cap: int, n_words: int,
         return (rr, cc, vv, n, bloom_build(rr, n_words, n_hashes),
                 fence_build(rr, block), rr[0], rr[jnp.maximum(n - 1, 0)])
 
-    return jax.jit(jax.vmap(one, in_axes=(0, 0, 0, 0)))
+    return jax.jit(jax.vmap(lsm_compact, in_axes=(0, 0, 0, 0)))
 
 
 @functools.partial(jax.jit, static_argnames=("max_return", "block"))
@@ -336,7 +337,7 @@ def _fused_query_fn(combiner: str, level_blocks: Tuple[int, ...],
 
     n_levels = len(level_blocks)
 
-    def fused(q, levels, l0, mem, filt=None):
+    def lsm_fused_query(q, levels, l0, mem, filt=None):
         seg_cols, seg_vals, seg_ok, seg_age, cnts, hits = [], [], [], [], [], []
         n_q = q.shape[0]
         iota = jnp.arange(max_return, dtype=jnp.int32)
@@ -449,7 +450,7 @@ def _fused_query_fn(combiner: str, level_blocks: Tuple[int, ...],
                     else jnp.zeros((0,), jnp.bool_))
         return col_s, jnp.where(keep, out_v, 0.0), keep, cnt_max, hits_vec
 
-    return jax.jit(fused)
+    return jax.jit(lsm_fused_query)
 
 
 @functools.lru_cache(maxsize=None)
@@ -494,7 +495,7 @@ def _fused_scan_fn(combiner: str, level_blocks: Tuple[int, ...], b0: int,
 
     n_levels = len(level_blocks)
 
-    def fused(lohi, levels, l0, mem, filt=None):
+    def lsm_fused_scan(lohi, levels, l0, mem, filt=None):
         iota = jnp.arange(width, dtype=jnp.int32)
         seg_r, seg_c, seg_v, seg_ok, seg_age, cnts = [], [], [], [], [], []
 
@@ -602,7 +603,7 @@ def _fused_scan_fn(combiner: str, level_blocks: Tuple[int, ...], b0: int,
         cnt_max = jnp.max(jnp.stack(cnts))
         return row_s, col_s, jnp.where(keep, out_v, 0.0), keep, cnt_max
 
-    return jax.jit(fused)
+    return jax.jit(lsm_fused_scan)
 
 
 def combine_triples(r: np.ndarray, c: np.ndarray, v: np.ndarray,
@@ -808,10 +809,8 @@ class LSMRuns:
         are major-compacted first — peers keep their L0 runs untouched.
         May raise OverflowError (capacity back-pressure, like the legacy
         engine)."""
-        t0 = perf_counter()
-        with self._trace.span("flush", table=self.name):
+        with self._trace.span("flush", self._h_flush, table=self.name):
             self._flush_memtable(mem_r, mem_c, mem_v)
-        self._h_flush.observe(perf_counter() - t0)
 
     def _flush_memtable(self, mem_r, mem_c, mem_v) -> None:
         rr, cc, vv, n, bb, ff, mn, mx = _flush_fn(
@@ -869,11 +868,9 @@ class LSMRuns:
         mask = np.asarray(mask, bool)
         if not mask.any():
             return
-        t0 = perf_counter()
-        with self._trace.span("major_compact", table=self.name,
-                              shards=int(mask.sum())):
+        with self._trace.span("major_compact", self._h_compact,
+                              table=self.name, shards=int(mask.sum())):
             self._major_compact(mask)
-        self._h_compact.observe(perf_counter() - t0)
 
     def _major_compact(self, mask: np.ndarray) -> None:
         d = self._pick_depth(mask)

@@ -141,9 +141,8 @@ class WriteAheadLog:
         ``tablet`` tags every triple in the frame as belonging to one
         tablet (the caller partitions a mixed batch into per-tablet
         frames), enabling per-tablet suffix replay."""
-        t0 = perf_counter()
-        with self._trace.span("wal.append", log=_wal_label(self.path),
-                              n=len(rows)):
+        with self._trace.span("wal.append", self._h_append,
+                              log=_wal_label(self.path), n=len(rows)):
             payload = (np.asarray(rows, "<i4").tobytes()
                        + np.asarray(cols, "<i4").tobytes()
                        + np.asarray(vals, "<f4").tobytes())
@@ -162,9 +161,8 @@ class WriteAheadLog:
                 os.fsync(self._f.fileno())
                 self._c_fsyncs.inc()
                 self._h_fsync.observe(perf_counter() - t1)
-        self._c_appends.inc()
-        self._c_bytes.inc(_REC.size + len(extra) + len(payload))
-        self._h_append.observe(perf_counter() - t0)
+            self._c_appends.inc()
+            self._c_bytes.inc(_REC.size + len(extra) + len(payload))
         return self._f.tell()
 
     def append_meta(self, op: dict) -> int:

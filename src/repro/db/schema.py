@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.assoc import Assoc
+from ..obs import span as obs_span
 from .connector import DBserver, TablePair, delete as _delete
 from .kvstore import degree_update
 
@@ -30,24 +31,31 @@ class DegreeTable:
         server.tables[name] = self
 
     def update(self, rid: np.ndarray, cid: np.ndarray) -> None:
-        ones_r = jnp.ones((len(rid),), jnp.float32)
-        self.out_deg = degree_update(self.out_deg, jnp.asarray(rid), ones_r,
-                                     use_pallas=False)
-        self.in_deg = degree_update(self.in_deg, jnp.asarray(cid),
-                                    jnp.ones((len(cid),), jnp.float32),
-                                    use_pallas=False)
+        with obs_span("degree.update", n=len(rid)):
+            ones_r = jnp.ones((len(rid),), jnp.float32)
+            self.out_deg = degree_update(self.out_deg, jnp.asarray(rid),
+                                         ones_r, use_pallas=False)
+            self.in_deg = degree_update(self.in_deg, jnp.asarray(cid),
+                                        jnp.ones((len(cid),), jnp.float32),
+                                        use_pallas=False)
 
     def degrees(self, vertices) -> Assoc:
-        ids = self.server.resolve_selector_plan(vertices).filter_ids()
-        if ids is None:
-            ids = np.arange(len(self.server.keydict), dtype=np.int32)
-        out = np.asarray(self.out_deg)[ids]
-        ind = np.asarray(self.in_deg)[ids]
-        keys = self.server.keydict.decode(ids)
-        rows = np.concatenate([keys, keys])
-        cols = np.asarray(["OutDeg"] * len(ids) + ["InDeg"] * len(ids), object)
-        vals = np.concatenate([out, ind])
-        return Assoc(rows, cols, vals)
+        with obs_span("schema.degrees", table=self.name):
+            with obs_span("connector.plan"):
+                ids = self.server.resolve_selector_plan(vertices).filter_ids()
+                if ids is None:
+                    ids = np.arange(len(self.server.keydict), dtype=np.int32)
+            with obs_span("degree.read", n=len(ids)):
+                out = np.asarray(self.out_deg)[ids]
+                ind = np.asarray(self.in_deg)[ids]
+            with obs_span("dict.decode", n=len(ids)):
+                keys = self.server.keydict.decode(ids)
+            with obs_span("assoc.build", n=len(ids)):
+                rows = np.concatenate([keys, keys])
+                cols = np.asarray(["OutDeg"] * len(ids) + ["InDeg"] * len(ids),
+                                  object)
+                vals = np.concatenate([out, ind])
+                return Assoc(rows, cols, vals)
 
     def vertices_with_degree(self, target: float, kind: str = "out",
                              tol: float = 10 ** 0.5) -> np.ndarray:
@@ -72,10 +80,13 @@ class EdgeSchema:
         self.put_triple(*a.triples())
 
     def put_triple(self, rows, cols, vals) -> None:
-        self.pair.put_triple(rows, cols, vals)
-        rid = self.server.keydict.lookup(np.asarray(rows, object))
-        cid = self.server.keydict.lookup(np.asarray(cols, object))
-        self.deg.update(rid, cid)
+        # ingest root: the pair write and the degree upkeep share one trace
+        with obs_span("schema.put", table=self.pair.name, n=len(rows)):
+            self.pair.put_triple(rows, cols, vals)
+            with obs_span("degree.lookup", n=len(rows)):
+                rid = self.server.keydict.lookup(np.asarray(rows, object))
+                cid = self.server.keydict.lookup(np.asarray(cols, object))
+            self.deg.update(rid, cid)
 
     def __getitem__(self, key) -> Assoc:
         return self.pair[key]

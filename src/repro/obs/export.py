@@ -197,9 +197,9 @@ def health_report(snapshot: dict = None, fmt: str = "md") -> str:
         default_registry().snapshot()
     gauges, counters, hists = [], {}, []
     for key, val in sorted(snap.items()):
-        name, labels = _parse_series_key(key)
+        name = _parse_series_key(key)[0]
         if isinstance(val, dict):
-            hists.append((name, labels, val))
+            hists.append((key, val))
         elif isinstance(val, float) or name.endswith(
                 ("_ratio", "_rate", "_occupancy", "_amplification",
                  "_bytes", "_entries", "_runs", "_shapes", "_debt")):
@@ -235,10 +235,10 @@ def health_report(snapshot: dict = None, fmt: str = "md") -> str:
     parts.append(table(("counter", "total"), sorted(counters.items())))
     parts.append(head("Latency histograms (s)"))
     rows = []
-    for name, labels, h in hists:
+    for key, h in hists:
         if not h.get("count"):
             continue
-        rows.append((_series_label(name, labels), h["count"],
+        rows.append((key, h["count"],
                      f"{h.get('p50', float('nan')):.3e}",
                      f"{h.get('p99', float('nan')):.3e}",
                      f"{h.get('max', float('nan')):.3e}",
@@ -246,13 +246,6 @@ def health_report(snapshot: dict = None, fmt: str = "md") -> str:
     parts.append(table(("series", "count", "p50", "p99", "max",
                         "exemplars"), rows))
     return "\n\n".join(parts) + "\n"
-
-
-def _series_label(name, labels):
-    if not labels:
-        return name
-    inner = ",".join(f"{k}={v}" for k, v in sorted(labels.items()))
-    return f"{name}{{{inner}}}"
 
 
 # ----------------------------------------------------------- debug bundle

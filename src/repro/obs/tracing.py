@@ -1,6 +1,7 @@
-"""Per-op span tracing: nested wall-time spans in a bounded ring buffer,
-a slow-op log, trace-context propagation, a flight recorder for slow
-ops, and Chrome-trace / plain-JSON export.
+"""Per-op span tracing: nested wall-time spans that reach the profiler's
+trace, per-span totals in the metrics registry, a bounded ring buffer, a
+slow-op log, trace-context propagation, a flight recorder for slow ops,
+and Chrome-trace / plain-JSON export.
 
     with span("flush", table="t", shard=3):
         ...
@@ -11,6 +12,20 @@ Spans record host wall time. Under JAX async dispatch that means a
 "dispatch" span measures enqueue cost and a "host_sync" span measures the
 device round-trip — which is exactly the split the fused read path is
 designed around (one dispatch + one sync per query batch).
+
+Profiler clock: an enabled span also enters a
+``jax.profiler.TraceAnnotation`` of its name, so every span shows up as a
+host event in any ``jax.profiler`` trace, on the same clock as the
+device's operations. With no profiler collecting that is a TraceMe that
+records nothing. jax is imported on the first span, and a process without
+jax gets no annotations.
+
+Totals: on exit every span observes its duration into the histogram
+``span_s{span=<name>}`` and adds its self time (duration minus the time
+its direct children covered) to the counter ``span_self_s{span=<name>}``
+of the default registry. A site that keeps a latency histogram of the
+same extent passes it as ``hist``: the span's one clock reading feeds it
+too (successful exits only), and a disabled tracer still times it.
 
 Trace context: the root span of each nesting (depth 0) allocates a trace
 id (``t<hex>``); every child span inherits it, so one connector-level op
@@ -33,6 +48,26 @@ import json
 import threading
 import time
 from collections import deque
+from time import perf_counter
+
+# wall clock = perf_counter + this, so a ring record's ``ts`` comes from
+# the span's own clock reading
+_WALL_OFFSET = time.time() - perf_counter()
+
+# jax.profiler.TraceAnnotation once the first span has looked; False when
+# jax cannot be imported
+_ANNOTATION = None
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
 class _NullSpan:
@@ -49,14 +84,32 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("tracer", "name", "labels", "t0", "ts", "depth", "parent",
-                 "trace")
+class _Timed:
+    """Disabled-tracer stand-in for a span that feeds a site histogram."""
+    __slots__ = ("hist", "t0")
 
-    def __init__(self, tracer, name, labels):
+    def __init__(self, hist):
+        self.hist = hist
+
+    def __enter__(self):
+        self.t0 = perf_counter()
+        return self
+
+    def __exit__(self, exc_type, *exc):
+        if exc_type is None:
+            self.hist.observe(perf_counter() - self.t0)
+        return False
+
+
+class _Span:
+    __slots__ = ("tracer", "name", "labels", "hist", "t0", "depth", "parent",
+                 "trace", "child_s", "ann")
+
+    def __init__(self, tracer, name, labels, hist):
         self.tracer = tracer
         self.name = name
         self.labels = labels
+        self.hist = hist
 
     def __enter__(self):
         tr = self.tracer
@@ -70,17 +123,30 @@ class _Span:
             self.trace = "t%06x" % next(tr._trace_seq)
             tr._local.tree = []
         stack.append(self)
-        self.ts = time.time()
-        self.t0 = time.perf_counter()
+        self.child_s = 0.0
+        cls = _ANNOTATION if _ANNOTATION is not None else _annotation()
+        self.ann = cls(self.name) if cls else None
+        if self.ann is not None:
+            self.ann.__enter__()
+        self.t0 = perf_counter()
         return self
 
-    def __exit__(self, *exc):
-        dur = time.perf_counter() - self.t0
+    def __exit__(self, exc_type, *exc):
+        dur = perf_counter() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
         tr = self.tracer
+        h_span, c_self = tr._instruments(self.name)
+        h_span.observe(dur)
+        c_self.inc(dur - self.child_s)
+        if self.hist is not None and exc_type is None:
+            self.hist.observe(dur)
         stack = tr._stack()
         if stack and stack[-1] is self:
             stack.pop()
-        rec = {"name": self.name, "ts": self.ts, "dur": dur,
+            if stack:
+                stack[-1].child_s += dur
+        rec = {"name": self.name, "ts": self.t0 + _WALL_OFFSET, "dur": dur,
                "depth": self.depth, "parent": self.parent,
                "trace": self.trace, "tid": threading.get_ident()}
         if self.labels:
@@ -102,8 +168,10 @@ class _Span:
 class Tracer:
     def __init__(self, capacity: int = 8192, slow_threshold_s: float = 0.050,
                  slow_capacity: int = 256, flight_capacity: int = 64,
-                 enabled: bool = True):
+                 enabled: bool = True, registry=None):
         self.enabled = enabled
+        self.registry = registry      # None: the default registry
+        self._inst: dict = {}         # span name -> (span_s, span_self_s)
         self.slow_threshold_s = slow_threshold_s
         self._ring = deque(maxlen=capacity)
         self._slow = deque(maxlen=slow_capacity)
@@ -117,10 +185,23 @@ class Tracer:
             st = self._local.stack = []
         return st
 
-    def span(self, name: str, **labels):
+    def _instruments(self, name: str):
+        inst = self._inst.get(name)
+        if inst is None:
+            reg = self.registry
+            if reg is None:
+                from .metrics import default_registry
+                reg = default_registry()
+            inst = self._inst[name] = (reg.histogram("span_s", span=name),
+                                       reg.counter("span_self_s", span=name))
+        return inst
+
+    def span(self, name: str, hist=None, **labels):
+        """A span named ``name``; ``hist``, a site's latency histogram of
+        the same extent, is fed the span's duration."""
         if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, labels)
+            return _NULL_SPAN if hist is None else _Timed(hist)
+        return _Span(self, name, labels, hist)
 
     def current_trace_id(self):
         """Trace id of the innermost open span on this thread, or None."""
@@ -181,9 +262,9 @@ def default_tracer() -> Tracer:
     return _DEFAULT
 
 
-def span(name: str, **labels):
+def span(name: str, hist=None, **labels):
     """Span on the process-global default tracer."""
-    return _DEFAULT.span(name, **labels)
+    return _DEFAULT.span(name, hist, **labels)
 
 
 def current_trace():

@@ -220,6 +220,109 @@ def test_disabled_tracer_hands_back_shared_null_span():
     assert tr.flight_recordings() == []
 
 
+def test_span_self_time_is_duration_less_direct_children():
+    reg = Registry()
+    tr = Tracer(slow_threshold_s=10.0, registry=reg)
+    with tr.span("root"):
+        time.sleep(0.02)
+        with tr.span("child"):
+            time.sleep(0.03)
+            with tr.span("grandchild"):
+                time.sleep(0.01)
+        with tr.span("child"):
+            time.sleep(0.01)
+    names = ("root", "child", "grandchild")
+    dur = {n: reg.histogram("span_s", span=n) for n in names}
+    own = {n: reg.counter("span_self_s", span=n).value for n in names}
+    assert [dur[n].count for n in names] == [1, 2, 1]
+    # self time = duration - the time the DIRECT children covered
+    assert own["root"] == pytest.approx(dur["root"].sum - dur["child"].sum,
+                                        abs=1e-9)
+    assert own["child"] == pytest.approx(
+        dur["child"].sum - dur["grandchild"].sum, abs=1e-9)
+    assert own["grandchild"] == pytest.approx(dur["grandchild"].sum,
+                                              abs=1e-9)
+    # ... and each is about the sleep written at that level
+    for n, slept in (("root", 0.02), ("child", 0.04), ("grandchild", 0.01)):
+        assert slept <= own[n] < slept + 0.05, (n, own[n])
+    # the ring records carry the same durations, stamped on the wall clock
+    recs = {r["name"]: r for r in tr.spans()}
+    assert recs["root"]["dur"] == pytest.approx(dur["root"].sum, abs=1e-12)
+    assert abs(recs["root"]["ts"] - time.time()) < 5.0
+
+
+class _RecordingAnnotation:
+    """Stand-in for jax.profiler.TraceAnnotation that logs enters/exits."""
+    log = []
+
+    def __init__(self, name, **kwargs):
+        assert not kwargs               # the name only, no labels
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+def test_enabled_spans_enter_a_profiler_annotation(monkeypatch):
+    from repro.obs import tracing
+    monkeypatch.setattr(tracing, "_ANNOTATION", _RecordingAnnotation)
+    monkeypatch.setattr(_RecordingAnnotation, "log", [])
+    tr = Tracer(registry=Registry())
+    with tr.span("outer", table="t"):
+        with tr.span("inner", n=3):
+            pass
+    assert _RecordingAnnotation.log == [
+        ("enter", "outer"), ("enter", "inner"),
+        ("exit", "inner"), ("exit", "outer")]
+
+
+def test_real_annotation_is_jax_trace_annotation():
+    import jax
+    with Tracer(registry=Registry()).span("probe") as sp:
+        assert isinstance(sp.ann, jax.profiler.TraceAnnotation)
+
+
+def test_disabled_tracer_makes_no_span_series_and_no_annotation(monkeypatch):
+    from repro.obs import tracing
+    monkeypatch.setattr(tracing, "_ANNOTATION", _RecordingAnnotation)
+    monkeypatch.setattr(_RecordingAnnotation, "log", [])
+    reg = Registry()
+    tr = Tracer(enabled=False, registry=reg)
+    with tr.span("a"):
+        with tr.span("b", x=1):
+            pass
+    assert reg.series("span_s") == [] and reg.series("span_self_s") == []
+    assert _RecordingAnnotation.log == []
+    assert tr.spans() == []
+
+
+def test_span_feeds_the_site_histogram_with_its_own_reading():
+    reg = Registry()
+    site = reg.histogram("db_op_latency_s", table="t", op="ingest")
+    tr = Tracer(registry=reg)
+    with tr.span("ingest", site, table="t"):
+        time.sleep(0.002)
+    assert site.count == 1
+    assert site.sum == reg.histogram("span_s", span="ingest").sum
+    with pytest.raises(RuntimeError):      # a failed op is not a latency
+        with tr.span("ingest", site):
+            raise RuntimeError("boom")
+    assert site.count == 1
+    assert reg.histogram("span_s", span="ingest").count == 2
+    # a disabled tracer still times the site (its own switch is the
+    # registry's), and makes no span series
+    off_reg = Registry()
+    off_site = off_reg.histogram("wal_latency_s", log="t", op="append")
+    off = Tracer(enabled=False, registry=off_reg)
+    with off.span("wal.append", off_site):
+        time.sleep(0.002)
+    assert off_site.count == 1 and off_site.sum >= 0.002
+    assert off_reg.series("span_s") == []
+
+
 # ------------------------------------ trace context + flight recorder
 def test_trace_id_propagation_root_allocates_children_inherit():
     tr = Tracer(slow_threshold_s=10.0)
