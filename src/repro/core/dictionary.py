@@ -15,6 +15,8 @@ from typing import Iterable, List
 
 import numpy as np
 
+from ..obs.metrics import default_registry
+
 
 class StringDict:
     """Bidirectional string <-> int32 id mapping (ids are dense, 0-based)."""
@@ -22,6 +24,10 @@ class StringDict:
     def __init__(self, strings: Iterable[str] = ()):  # noqa: D107
         self._to_id: dict = {}
         self._to_str: List[str] = []
+        # id -> string object array for decode: the first _mirrored entries
+        # of _to_str, in a buffer that doubles as it grows.
+        self._mirror = np.empty(0, dtype=object)
+        self._mirrored = 0
         if strings:
             self.encode(np.asarray(list(strings), dtype=object))
 
@@ -60,8 +66,28 @@ class StringDict:
         )
 
     def decode(self, ids: np.ndarray) -> np.ndarray:
-        arr = np.asarray(self._to_str, dtype=object)
-        return arr[np.asarray(ids)]
+        """Strings of ``ids``, indexed as a list of all interned strings is.
+
+        Gathers from the object-array mirror of ``_to_str`` (pointers, not
+        copies of the strings). Only decode syncs it: the keys interned since
+        the last decode are copied in, into a buffer whose capacity doubles,
+        so a decode costs its gather plus the new tail, never a rebuild of
+        the whole dictionary. ``dict_decode_mirrored_keys`` counts the keys
+        copied.
+        """
+        n = len(self._to_str)
+        done = self._mirrored
+        if n > done:
+            mirror = self._mirror
+            if n > len(mirror):
+                grown = np.empty(max(n, 2 * len(mirror)), dtype=object)
+                grown[:done] = mirror[:done]
+                self._mirror = mirror = grown
+            mirror[done:n] = self._to_str[done:n]
+            self._mirrored = n
+            reg = default_registry()
+            reg.counter("dict_decode_mirrored_keys").inc(n - done)
+        return self._mirror[:n][np.asarray(ids)]
 
     def get(self, s: str) -> int:
         return self._to_id.get(s, -1)
