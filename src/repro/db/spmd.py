@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from time import perf_counter
 from typing import Tuple
 
 import jax
@@ -25,36 +24,56 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..kernels.common import I32_MAX
-from ..obs import default_registry, merge_snapshots
+from ..obs import default_registry, merge_snapshots, span
 from .kvstore import Tablet, shard_of_dev, tablet_insert
 
 
-def _instrumented(fn, op: str):
-    """Host-side step instrumentation: per-process step counters + dispatch
-    wall-time histograms (JAX dispatch is async; the histogram measures
-    enqueue cost, not device compute). The raw jitted fn stays reachable as
+def _instrumented(fn, op: str, work=None):
+    """Host-side step instrumentation: a program span around the dispatch,
+    per-process step counters, dispatch wall-time histograms and the
+    step's work counters. The raw jitted fn stays reachable as
     ``step.__wrapped__`` for callers that re-jit / AOT-lower the step
     (launch/ingest.py does).
+
+    Span: each call runs inside the ``repro.obs.tracing`` span named after
+    the step (``spmd_lsm_ingest`` -> ``spmd.lsm_ingest``), so it reaches a
+    profiler trace beside the device's operations and keeps
+    ``span_s``/``span_self_s{span=spmd.<step>}``; its one clock reading
+    feeds ``db_op_latency_s{table=spmd,op=<op>}``. JAX dispatch is async:
+    the span covers the host's dispatch (enqueue) only, and the caller's
+    first read of a result (the stack height ``k``, the level size ``n``)
+    is what waits for the device.
+
+    Work: ``work`` maps a counter name to a function of the call's
+    arguments that gives the step's amount from their static shapes, so no
+    counter syncs with the device. ``spmd_exchange_slots{op}`` counts the
+    entries the ``all_to_all`` ships (S x S x bcap a routing leg) and
+    ``spmd_compact_entries{op}`` the entries the merge reads
+    (S x (slots x run capacity + level capacity)). Both count padded
+    slots: the work the kernels are given, not the live entries.
 
     Compile/retrace telemetry: a jitted step's compile-cache growing after
     a call means a fresh input shape signature traced — counted into
     ``lsm_retraces{table=spmd}`` so the registry can assert steady-state
     steps never recompile (same guarantee the fused read path makes)."""
     reg = default_registry()
+    name = "spmd." + op.removeprefix("spmd_")
     c_steps = reg.counter("spmd_steps", op=op)
     c_retrace = reg.counter("lsm_retraces", table="spmd", op=op)
     g_shapes = reg.gauge("lsm_compiled_shapes", table="spmd", op=op)
     h_step = reg.histogram("db_op_latency_s", table="spmd", op=op)
+    c_work = [(reg.counter(k, op=op), f) for k, f in (work or {}).items()]
     cache_size = getattr(fn, "_cache_size", None)
     state = {"n": cache_size() if cache_size else 0}
 
     def step(*args, **kw):
         if not reg.enabled:
             return fn(*args, **kw)
-        t0 = perf_counter()
-        out = fn(*args, **kw)
+        with span(name, h_step):
+            out = fn(*args, **kw)
         c_steps.inc()
-        h_step.observe(perf_counter() - t0)
+        for c, f in c_work:
+            c.inc(f(*args))
         if cache_size is not None:
             n = cache_size()
             if n > state["n"]:
@@ -94,7 +113,7 @@ def make_spmd_ingest_step(mesh, axis: str, num_shards: int, id_capacity: int,
                           combiner: str = "last", use_pallas: bool = False):
     """Build the jitted SPMD ingest step for ``mesh`` (S = mesh axis size)."""
 
-    def shard_fn(tablet: Tablet, br, bc, bv):
+    def spmd_ingest(tablet: Tablet, br, bc, bv):
         # local views: tablet leaves [1, cap], batch [1, bcap]
         t = jax.tree.map(lambda x: x[0], tablet)
         send = _bucket_local(br[0], bc[0], bv[0], num_shards, id_capacity)
@@ -108,11 +127,12 @@ def make_spmd_ingest_step(mesh, axis: str, num_shards: int, id_capacity: int,
 
     spec_t = Tablet(rows=P(axis, None), cols=P(axis, None),
                     vals=P(axis, None), n=P(axis))
-    fn = jax.shard_map(shard_fn, mesh=mesh,
+    fn = jax.shard_map(spmd_ingest, mesh=mesh,
                        in_specs=(spec_t, P(axis, None), P(axis, None),
                                  P(axis, None)),
                        out_specs=spec_t, check_vma=False)
-    return _instrumented(jax.jit(fn), "spmd_ingest")
+    return _instrumented(jax.jit(fn), "spmd_ingest", {
+        "spmd_exchange_slots": lambda t, br, *_: num_shards * br.size})
 
 
 def stacked_empty(num_shards: int, capacity: int) -> Tablet:
@@ -167,7 +187,7 @@ def make_spmd_lsm_ingest_step(mesh, axis: str, num_shards: int,
     """
     from .kvstore import _dedup_combine
 
-    def shard_fn(l0: L0Stack, br, bc, bv):
+    def spmd_lsm_ingest(l0: L0Stack, br, bc, bv):
         me = jax.tree.map(lambda x: x[0], l0)
         send = _bucket_local(br[0], bc[0], bv[0], num_shards, id_capacity)
         rr = jax.lax.all_to_all(send[0], axis, 0, 0).reshape(-1)
@@ -190,11 +210,12 @@ def make_spmd_lsm_ingest_step(mesh, axis: str, num_shards: int,
                       k=jnp.minimum(me.k + 1, slots))
         return jax.tree.map(lambda x: x[None], new)
 
-    fn = jax.shard_map(shard_fn, mesh=mesh,
+    fn = jax.shard_map(spmd_lsm_ingest, mesh=mesh,
                        in_specs=(_l0_spec(axis), P(axis, None), P(axis, None),
                                  P(axis, None)),
                        out_specs=_l0_spec(axis), check_vma=False)
-    return _instrumented(jax.jit(fn), "spmd_lsm_ingest")
+    return _instrumented(jax.jit(fn), "spmd_lsm_ingest", {
+        "spmd_exchange_slots": lambda l0, br, *_: num_shards * br.size})
 
 
 def _bucket_local_tablets(br, bc, bv, splits, owners, num_shards: int):
@@ -229,7 +250,7 @@ def make_spmd_tablet_ingest_step(mesh, axis: str, num_shards: int,
     ``_bucket_local_tablets``)."""
     from .kvstore import _dedup_combine
 
-    def shard_fn(l0: L0Stack, br, bc, bv, splits, owners):
+    def spmd_tablet_ingest(l0: L0Stack, br, bc, bv, splits, owners):
         me = jax.tree.map(lambda x: x[0], l0)
         send = _bucket_local_tablets(br[0], bc[0], bv[0], splits, owners,
                                      num_shards)
@@ -251,11 +272,12 @@ def make_spmd_tablet_ingest_step(mesh, axis: str, num_shards: int,
                       k=jnp.minimum(me.k + 1, slots))
         return jax.tree.map(lambda x: x[None], new)
 
-    fn = jax.shard_map(shard_fn, mesh=mesh,
+    fn = jax.shard_map(spmd_tablet_ingest, mesh=mesh,
                        in_specs=(_l0_spec(axis), P(axis, None), P(axis, None),
                                  P(axis, None), P(), P()),
                        out_specs=_l0_spec(axis), check_vma=False)
-    return _instrumented(jax.jit(fn), "spmd_tablet_ingest")
+    return _instrumented(jax.jit(fn), "spmd_tablet_ingest", {
+        "spmd_exchange_slots": lambda l0, br, *_: num_shards * br.size})
 
 
 def make_spmd_lsm_pair_ingest_step(mesh, axis: str, num_shards: int,
@@ -295,7 +317,7 @@ def make_spmd_lsm_pair_ingest_step(mesh, axis: str, num_shards: int,
                        vals=me.vals.at[me.k].set(run[2], mode="drop"),
                        k=jnp.minimum(me.k + 1, slots))
 
-    def shard_fn(l0: L0Stack, l0t: L0Stack, br, bc, bv):
+    def spmd_lsm_pair_ingest(l0: L0Stack, l0t: L0Stack, br, bc, bv):
         me = jax.tree.map(lambda x: x[0], l0)
         met = jax.tree.map(lambda x: x[0], l0t)
         # rows and cols share one id space, so the SAME shard_of routes
@@ -305,12 +327,15 @@ def make_spmd_lsm_pair_ingest_step(mesh, axis: str, num_shards: int,
         return (jax.tree.map(lambda x: x[None], append(me, fwd)),
                 jax.tree.map(lambda x: x[None], append(met, twd)))
 
-    fn = jax.shard_map(shard_fn, mesh=mesh,
+    fn = jax.shard_map(spmd_lsm_pair_ingest, mesh=mesh,
                        in_specs=(_l0_spec(axis), _l0_spec(axis), P(axis, None),
                                  P(axis, None), P(axis, None)),
                        out_specs=(_l0_spec(axis), _l0_spec(axis)),
                        check_vma=False)
-    return _instrumented(jax.jit(fn), "spmd_lsm_pair_ingest")
+    # two routing legs: forward by row owner, transpose by column owner
+    return _instrumented(jax.jit(fn), "spmd_lsm_pair_ingest", {
+        "spmd_exchange_slots": lambda l0, l0t, br, *_:
+            2 * num_shards * br.size})
 
 
 def make_spmd_lsm_query_step(mesh, axis: str, combiner: str = "last",
@@ -348,7 +373,7 @@ def make_spmd_lsm_query_step(mesh, axis: str, combiner: str = "last",
         idxc = jnp.clip(idx, 0, cap - 1)
         return cols[idxc], vals[idxc], ok
 
-    def shard_fn(l0: L0Stack, level: Tablet, q):
+    def spmd_lsm_query(l0: L0Stack, level: Tablet, q):
         me = jax.tree.map(lambda x: x[0], l0)
         lv = jax.tree.map(lambda x: x[0], level)
         qq = q[0]
@@ -374,7 +399,7 @@ def make_spmd_lsm_query_step(mesh, axis: str, combiner: str = "last",
         )(col_s, val_s)
         return (col_s[None], jnp.where(keep, out_v, 0.0)[None], keep[None])
 
-    fn = jax.shard_map(shard_fn, mesh=mesh,
+    fn = jax.shard_map(spmd_lsm_query, mesh=mesh,
                        in_specs=(_l0_spec(axis), Tablet(rows=P(axis, None),
                                                         cols=P(axis, None),
                                                         vals=P(axis, None),
@@ -441,7 +466,7 @@ def make_spmd_lsm_scan_step(mesh, axis: str, combiner: str = "last",
         idxc = jnp.clip(idx, 0, cap - 1)
         return rows[idxc], cols[idxc], vals[idxc], idx < end, end - start
 
-    def shard_fn(l0: L0Stack, level: Tablet, bounds):
+    def spmd_lsm_scan(l0: L0Stack, level: Tablet, bounds):
         me = jax.tree.map(lambda x: x[0], l0)
         lv = jax.tree.map(lambda x: x[0], level)
         lohi = bounds[0]
@@ -469,7 +494,7 @@ def make_spmd_lsm_scan_step(mesh, axis: str, combiner: str = "last",
 
     spec_t = Tablet(rows=P(axis, None), cols=P(axis, None),
                     vals=P(axis, None), n=P(axis))
-    fn = jax.shard_map(shard_fn, mesh=mesh,
+    fn = jax.shard_map(spmd_lsm_scan, mesh=mesh,
                        in_specs=(_l0_spec(axis), spec_t, P(axis, None)),
                        out_specs=(P(axis, None), P(axis, None), P(axis, None),
                                   P(axis, None), P(axis)), check_vma=False)
@@ -484,7 +509,7 @@ def make_spmd_lsm_compact_step(mesh, axis: str, combiner: str = "last",
     from ..kernels.merge_rank import kway_merge
     from .kvstore import _dedup_combine
 
-    def shard_fn(l0: L0Stack, level: Tablet):
+    def spmd_lsm_compact(l0: L0Stack, level: Tablet):
         me = jax.tree.map(lambda x: x[0], l0)
         lv = jax.tree.map(lambda x: x[0], level)
         slots = me.rows.shape[0]
@@ -511,7 +536,9 @@ def make_spmd_lsm_compact_step(mesh, axis: str, combiner: str = "last",
 
     spec_t = Tablet(rows=P(axis, None), cols=P(axis, None),
                     vals=P(axis, None), n=P(axis))
-    fn = jax.shard_map(shard_fn, mesh=mesh,
+    fn = jax.shard_map(spmd_lsm_compact, mesh=mesh,
                        in_specs=(_l0_spec(axis), spec_t),
                        out_specs=(_l0_spec(axis), spec_t), check_vma=False)
-    return _instrumented(jax.jit(fn), "spmd_lsm_compact")
+    return _instrumented(jax.jit(fn), "spmd_lsm_compact", {
+        "spmd_compact_entries": lambda l0, level:
+            l0.rows.size + level.rows.size})
