@@ -35,9 +35,11 @@ Two read paths serve point queries (neither ever flushes):
   resident run, combined on the host. Kept as the A/B baseline
   (``fused_reads=False``) and for the stale-mirror recovery corners.
 
-All state is stacked [S, ...] across shards; flushes and compactions are
-vmapped so the S simulated tablet servers advance in lockstep (one hot
-shard compacts its peers early — harmless, entries just move down a level).
+All state is stacked [S, ...] across shards. A flush is vmapped over the
+S simulated tablet servers; a major compaction is one dispatch that visits
+the shards in turn and merges only those holding runs to merge (the
+others return their target level unchanged), and the host keeps the
+merged output of the shards it asked for.
 """
 from __future__ import annotations
 
@@ -161,20 +163,28 @@ def _write_slot_fn():
 @functools.lru_cache(maxsize=None)
 def _compact_fn(combiner: str, use_pallas: bool, out_cap: int, n_words: int,
                 block: int, n_hashes: int):
-    """jit(vmap): k-way merge L0 runs + levels 1..d into level d.
+    """jit: k-way merge L0 runs + levels 1..d into level d, shard by shard.
 
-    Inputs per shard: l0 [K0, m] plus a tuple of level runs ordered
-    DEEPEST FIRST (deepest = oldest). kway_merge keeps age order within
-    equal-key groups, so one dedup pass applies the combiner exactly.
+    Inputs are stacked over S shards: l0 [S, K0, m] plus a tuple of level
+    runs [S, cap] ordered DEEPEST FIRST (deepest = oldest; the first is
+    the target level d). kway_merge keeps age order within equal-key
+    groups, so one dedup pass applies the combiner exactly.
+
+    A shard merges only if a run besides the target holds an entry (runs
+    are sorted with I32_MAX padding at the tail, so a non-empty run's
+    first row is below I32_MAX); otherwise it returns the target as it
+    stands, since a sorted, deduped run merged with nothing is itself.
+    The shards go one after another (``lax.map``): under ``vmap`` the
+    ``cond`` would become a select that runs the merge for every shard.
     The program is named ``lsm_compact`` in a profiler trace.
     """
+    from ..kvstore import _dedup_combine
 
-    def lsm_compact(l0_r, l0_c, l0_v, lvls):
+    def merge(l0_r, l0_c, l0_v, lvls):
         runs = [lv for lv in lvls]
         runs += [(l0_r[k], l0_c[k], l0_v[k]) for k in range(l0_r.shape[0])]
         mr, mc, mv = kway_merge(runs, use_pallas=use_pallas,
                                 interpret=INTERPRET)
-        from ..kvstore import _dedup_combine
         keep, out_v = _dedup_combine(mr, mc, mv, combiner)
         pos = jnp.cumsum(keep) - 1
         idx = jnp.where(keep, pos, out_cap)
@@ -185,7 +195,28 @@ def _compact_fn(combiner: str, use_pallas: bool, out_cap: int, n_words: int,
         return (rr, cc, vv, n, bloom_build(rr, n_words, n_hashes),
                 fence_build(rr, block), rr[0], rr[jnp.maximum(n - 1, 0)])
 
-    return jax.jit(jax.vmap(lsm_compact, in_axes=(0, 0, 0, 0)))
+    def keep_target(l0_r, l0_c, l0_v, lvls):
+        rr, cc, vv = lvls[0]
+        n = (rr != I32_MAX).sum().astype(jnp.int32)
+        # bloom_build scatters over the whole capacity; an empty run's
+        # filter is all zeros, which is what it would build
+        bloom = jax.lax.cond(
+            n > 0, lambda r: bloom_build(r, n_words, n_hashes),
+            lambda r: jnp.zeros((n_words,), jnp.uint32), rr)
+        return (rr, cc, vv, n, bloom, fence_build(rr, block), rr[0],
+                rr[jnp.maximum(n - 1, 0)])
+
+    def shard(args):
+        l0_r, l0_c, l0_v, lvls = args
+        firsts = [l0_r[:, 0]] + [lv[0][:1] for lv in lvls[1:]]
+        pending = jnp.any(jnp.concatenate(firsts) != I32_MAX)
+        return jax.lax.cond(pending, merge, keep_target,
+                            l0_r, l0_c, l0_v, lvls)
+
+    def lsm_compact(l0_r, l0_c, l0_v, lvls):
+        return jax.lax.map(shard, (l0_r, l0_c, l0_v, lvls))
+
+    return jax.jit(lsm_compact)
 
 
 @functools.partial(jax.jit, static_argnames=("max_return", "block"))
@@ -767,10 +798,15 @@ class LSMRuns:
                                                   table=name)
         self._c_compact_entries = self._reg.counter("lsm_compact_entries",
                                                     table=name)
+        # shards whose compaction merge was skipped (nothing but the
+        # target level to merge), summed over compactions
+        self._c_compact_skipped = self._reg.counter(
+            "lsm_compact_skipped_shards", table=name)
         for inst in ([self._h_flush, self._h_compact]
                      + list(self._ctr.values())
                      + [self._c_retrace_q, self._c_retrace_s,
-                        self._c_flush_entries, self._c_compact_entries]
+                        self._c_flush_entries, self._c_compact_entries,
+                        self._c_compact_skipped]
                      + self._c_shard_flush + self._c_shard_compact):
             inst.reset()
         # per-run sliced views of the stacked arrays (slicing copies ~MBs
@@ -858,8 +894,9 @@ class LSMRuns:
         levels 1..d into level d (Pallas merge_rank under ``use_pallas``).
 
         ``mask`` selects WHICH shards compact (default: every shard with
-        L0 data). The merge itself stays one vmapped dispatch over all S
-        shards (static shapes); unmasked shards' merged output is simply
+        L0 data). The merge itself stays one dispatch over all S shards
+        (static shapes) in which a shard with nothing besides its target
+        level skips its merge; unmasked shards' output is simply
         discarded — their runs, counts, and L0 slots are untouched, so a
         single hot shard filling its L0 no longer forces a lockstep merge
         of every peer."""
@@ -875,6 +912,11 @@ class LSMRuns:
     def _major_compact(self, mask: np.ndarray) -> None:
         d = self._pick_depth(mask)
         target = self.levels[d]
+        # the host mirror of the program's per-shard test: a shard merges
+        # if an L0 slot or a shallower level holds entries
+        pending = self.l0_n.sum(axis=1) > 0
+        for lv in self.levels[:d]:
+            pending |= lv["n"] > 0
         # deepest first = oldest first (kway_merge contract)
         lvls = tuple((self.levels[i]["rows"], self.levels[i]["cols"],
                       self.levels[i]["vals"]) for i in range(d, -1, -1))
@@ -926,6 +968,7 @@ class LSMRuns:
         self._view_cache.clear()
         self._ctr["major_compactions"].inc()
         self._c_compact_entries.inc(int(n_host[mask].sum()))
+        self._c_compact_skipped.inc(int((~pending).sum()))
         for s in np.flatnonzero(mask):
             self._c_shard_compact[s].inc()
 
