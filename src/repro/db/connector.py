@@ -200,7 +200,7 @@ class DBserver:
 
     ``config`` (a ``kvstore.StoreConfig``) is the single source of truth
     for engine/topology settings; the legacy per-field attributes
-    (``num_shards``, ``engine``, ...) are read-only views of it. Extra
+    (``num_shards``, ``l0_slots``, ...) are read-only views of it. Extra
     keyword arguments override config fields (``DBserver("x",
     num_shards=8)`` still works)."""
 
@@ -233,8 +233,6 @@ class DBserver:
     batch_cap = property(lambda self: self.config.batch_cap)
     id_capacity = property(lambda self: self.config.id_capacity)
     use_pallas = property(lambda self: self.config.use_pallas)
-    engine = property(lambda self: self.config.engine)
-    fused_reads = property(lambda self: self.config.fused_reads)
     fused_q_limit = property(lambda self: self.config.fused_q_limit)
     l0_slots = property(lambda self: self.config.l0_slots)
     fanout = property(lambda self: self.config.fanout)
@@ -465,7 +463,7 @@ class DBserver:
                "tables": {}, "aggregate": {}}
         for name in live:
             store = self.tables[name].store
-            tbl = {"engine": store.engine,
+            tbl = {"engine": "lsm",
                    "counters": store.engine_stats(),
                    "latency_s": {op: pooled("db_op_latency_s", [name], op=op)
                                  for op in self._METRIC_OPS},
@@ -568,19 +566,18 @@ class DBserver:
             if store is None or store._closed:
                 continue
             store.refresh_health_gauges(bloom_probes=bloom_probes)
-            geo = {"engine": store.engine,
+            runs = store._runs
+            geo = {"engine": "lsm",
                    "num_shards": store.S,
                    "memtable_cap": store.mem_cap,
                    "memtable_n": [int(x) for x in store._mem_n],
-                   "stats": store.engine_stats()}
-            if store.engine == "lsm":
-                runs = store._runs
-                geo["level_caps"] = list(runs.level_caps)
-                geo["l0_slots"] = runs.K0
-                geo["resident_runs"] = [runs.resident_runs(s)
-                                        for s in range(store.S)]
-                geo["level_entries_per_shard"] = [
-                    [int(n) for n in lv["n"]] for lv in runs.levels]
+                   "stats": store.engine_stats(),
+                   "level_caps": list(runs.level_caps),
+                   "l0_slots": runs.K0,
+                   "resident_runs": [runs.resident_runs(s)
+                                     for s in range(store.S)],
+                   "level_entries_per_shard": [
+                       [int(n) for n in lv["n"]] for lv in runs.levels]}
             geometry[name] = geo
         extra = {
             "store_config": dataclasses.asdict(self.config),
@@ -888,8 +885,7 @@ def recover_connector(wal_root: str, name,
     cfg = man["config"]
     server = DBserver(
         instance,
-        config=StoreConfig.from_manifest(cfg).replace(engine="lsm",
-                                                      transpose=False))
+        config=StoreConfig.from_manifest(cfg).replace(transpose=False))
     # dictionary state must load BEFORE the journal re-opens for append
     server.keydict = _load_dict(wal_root, "keydict")
     server.attach_wal_root(wal_root)

@@ -2,35 +2,30 @@
 
 Each *tablet* holds (row_id, col_id) -> value entries on one mesh shard,
 range-partitioned by row id (pre-split tablets, as in the 100M-inserts/s
-Accumulo+D4M setup the paper cites). Two storage engines (see
-``src/repro/db/README.md``):
+Accumulo+D4M setup the paper cites). One storage engine serves every
+table (see ``src/repro/db/README.md``): leveled sorted runs
+(``repro.db.lsm``). Memtable flushes are O(memtable), major compactions
+k-way merge runs (Pallas ``merge_rank`` under ``use_pallas``), reads go
+through bloom filters + fence pointers in one fused dispatch per shard
+without flushing, and a WAL + snapshots provide crash recovery.
 
-  * ``engine="lsm"`` (default) — leveled sorted runs (``repro.db.lsm``):
-    memtable flushes are O(memtable), major compactions k-way merge runs
-    with the Pallas ``merge_rank`` kernel, reads go through bloom filters
-    + fence pointers without flushing, and a WAL + snapshots provide
-    crash recovery.
-  * ``engine="single"`` — one fixed-capacity sorted run per shard; every
-    flush merge-ranks the memtable into it (Pallas ``merge_rank``).
-    Queries are rank searches (Pallas ``sorted_search``) + bounded
-    gathers. Kept as the A/B baseline.
-
-Duplicate keys combine with Accumulo iterator semantics in both engines
-(last-wins versioning, sum/min/max combiners — ``db.iterators``).
+Duplicate keys combine with Accumulo iterator semantics (last-wins
+versioning, sum/min/max combiners — ``db.iterators``).
 
 All device functions are jit-compatible (static capacities, explicit valid
 counts, I32_MAX key padding). Two drivers exist:
-  * ``ShardedTable``      — stacked [S, cap] tablets on one device; used for
-                             CPU benchmarking of k-way ingest (paper Fig. 3).
+  * ``ShardedTable``      — stacked [S, cap] shards on one device.
   * ``repro.db.spmd``     — shard_map driver with all_to_all mutation routing
-                             for real meshes (and the multi-pod dry-run).
+                             for real meshes (and the multi-pod dry-run);
+                             ``Tablet`` and ``tablet_insert`` below serve
+                             its single-run ingest and level runs.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 from time import perf_counter
-from typing import Tuple
+from typing import Literal
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +35,6 @@ from ..kernels.common import I32_MAX, INTERPRET
 from ..obs import default_registry, default_tracer
 from ..kernels.merge_rank import merge_sorted
 from ..kernels.merge_rank.ref import merge_sorted_ref
-from ..kernels.sorted_search import sorted_search
 from ..kernels.segment_reduce import segment_sum
 
 COMBINERS = ("last", "sum", "min", "max")
@@ -71,20 +65,41 @@ class StoreConfig:
     (format 3) and splits/moves journal as WAL meta frames, so recovery
     rebuilds the exact topology. Off by default: the static path is
     byte-for-byte unchanged (WAL frames stay untagged).
+
+    ``engine`` and ``fused_reads`` each have one legal value: the LSM
+    engine and its fused read path are the only ones. They stay fields
+    so configs and manifests that name them keep loading; any other
+    value raises ``ValueError``.
     """
     num_shards: int = 4
     capacity_per_shard: int = 1 << 18
     batch_cap: int = 1 << 15
     id_capacity: int = 1 << 22
     use_pallas: bool = False
-    engine: str = "lsm"
-    fused_reads: bool = True
+    engine: Literal["lsm"] = "lsm"
+    fused_reads: Literal[True] = True
     fused_q_limit: int = 512
     l0_slots: int = 4
     fanout: int = 4
     memtable_cap: int = None
     transpose: bool = False
     dynamic_tablets: bool = False
+
+    def __post_init__(self):
+        if self.engine != "lsm":
+            raise ValueError(
+                f"engine={self.engine!r} is not supported: the LSM engine "
+                "(engine='lsm') is the only storage engine")
+        if self.fused_reads is not True:
+            raise ValueError(
+                f"fused_reads={self.fused_reads!r} is not supported: reads "
+                "always take the fused path (fused_reads=True)")
+        q = self.fused_q_limit
+        if (not isinstance(q, (int, np.integer)) or isinstance(q, bool)
+                or q <= 0):
+            raise ValueError(
+                f"fused_q_limit={q!r}: the fused read path's query tile "
+                "must be a positive int")
 
     def replace(self, **kw) -> "StoreConfig":
         return dataclasses.replace(self, **kw)
@@ -173,28 +188,6 @@ def tablet_insert(t: Tablet, br, bc, bv, combiner: str = "last",
     )
 
 
-@functools.partial(jax.jit, static_argnames=("max_return", "use_pallas"))
-def tablet_query_rows(t: Tablet, q: jax.Array, max_return: int,
-                      use_pallas: bool = True):
-    """Point row queries: all (col, val) for each row id in ``q``.
-
-    Returns (cols[Q, max_return], vals[Q, max_return], valid[Q, max_return],
-    counts[Q]); counts may exceed max_return (host re-queries with a larger
-    bound — Accumulo batch-scanner buffer semantics).
-    """
-    if use_pallas:
-        start = sorted_search(t.rows, q, "left", interpret=INTERPRET)
-        end = sorted_search(t.rows, q, "right", interpret=INTERPRET)
-    else:
-        start = jnp.searchsorted(t.rows, q, side="left").astype(jnp.int32)
-        end = jnp.searchsorted(t.rows, q, side="right").astype(jnp.int32)
-    cap = t.rows.shape[0]
-    idx = start[:, None] + jnp.arange(max_return, dtype=jnp.int32)[None, :]
-    ok = idx < end[:, None]
-    idxc = jnp.clip(idx, 0, cap - 1)
-    return t.cols[idxc], t.vals[idxc], ok, end - start
-
-
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
 def degree_update(deg: jax.Array, ids: jax.Array, weights: jax.Array,
                   use_pallas: bool = True) -> jax.Array:
@@ -254,18 +247,6 @@ def _memtable_append_flat(mem_r, mem_c, mem_v, counts, dest, slot, r, c, v):
 
 _APPEND = jax.jit(_memtable_append)
 _APPEND_FLAT = jax.jit(_memtable_append_flat)
-_INSERT_CACHE: dict = {}
-
-
-def _vmapped_insert(combiner: str, use_pallas: bool):
-    """Module-level jit cache: compiled minor compactions persist across
-    ShardedTable instances (benchmarks create many)."""
-    key = (combiner, use_pallas)
-    if key not in _INSERT_CACHE:
-        _INSERT_CACHE[key] = jax.jit(
-            jax.vmap(functools.partial(tablet_insert, combiner=combiner,
-                                       use_pallas=use_pallas)))
-    return _INSERT_CACHE[key]
 
 
 class ShardedTable:
@@ -275,18 +256,13 @@ class ShardedTable:
     execution path with identical per-shard code is ``repro.db.spmd``.
 
     Writes land in a per-shard *memtable* (unsorted fixed buffer); a minor
-    compaction happens only when the memtable fills. Two storage engines sit
-    under that memtable:
+    compaction happens only when the memtable fills. Under it sit leveled
+    sorted runs (``db.lsm``): a flush costs O(memtable), major compactions
+    k-way merge runs via the Pallas merge_rank kernel, and reads serve
+    from memtable + runs through bloom filters and fence pointers in one
+    fused dispatch per shard, WITHOUT flushing.
 
-      * ``engine="lsm"`` (default) — leveled sorted runs (``db.lsm``):
-        flush costs O(memtable), major compactions k-way merge runs via the
-        Pallas merge_rank kernel, and reads serve from memtable + runs
-        through bloom filters and fence pointers WITHOUT flushing.
-      * ``engine="single"`` — the legacy single-sorted-run tablet: every
-        flush merge-ranks the memtable into one O(capacity) run (kept for
-        A/B benchmarking; reads flush owner shards first).
-
-    With ``wal_dir`` set (LSM only), every ``insert`` batch is logged to an
+    With ``wal_dir`` set, every ``insert`` batch is logged to an
     append-only WAL before it reaches the memtable, ``checkpoint()``
     snapshots the runs, and ``db.lsm.recover(dir)`` rebuilds the table
     after a crash.
@@ -318,37 +294,18 @@ class ShardedTable:
             if v is not None}
         if overrides:
             cfg = dataclasses.replace(cfg, **overrides)
-        if cfg.engine not in ("lsm", "single"):
-            raise ValueError(f"unknown engine {cfg.engine!r}")
-        if cfg.transpose and cfg.engine != "lsm":
-            raise ValueError("transpose pairs require engine='lsm'")
-        if cfg.dynamic_tablets and cfg.engine != "lsm":
-            raise ValueError("dynamic_tablets requires engine='lsm'")
         self.config = cfg
         self.name = name
-        self.engine = cfg.engine
         self.S = cfg.num_shards
         self.cap = cfg.capacity_per_shard
         self.batch_cap = cfg.batch_cap
         self.id_capacity = cfg.id_capacity
         self.combiner = combiner
-        self.use_pallas = cfg.use_pallas
-        # fused_reads: serve LSM point queries via the fused path
-        # (db.lsm.engine.query_shard_fused); fused_q_limit is the QUERY
-        # TILE — batches beyond the tiny point bucket pad UP to it and
-        # larger ones split into fixed-size tiles (one jit cache entry
-        # serves every batch size, block bloom-gated per run), never the
-        # per-run fallback. fused_reads=False keeps the per-run baseline.
-        self.fused_reads = cfg.fused_reads
+        # fused_q_limit is the QUERY TILE of the fused read path: batches
+        # beyond the tiny point bucket pad UP to it and larger ones split
+        # into fixed-size tiles (one jit cache entry serves every batch
+        # size, block bloom-gated per run)
         self.fused_q_limit = cfg.fused_q_limit
-        # resolved locals for the body below (kwargs may have been None)
-        num_shards = cfg.num_shards
-        capacity_per_shard = cfg.capacity_per_shard
-        id_capacity = cfg.id_capacity
-        engine = cfg.engine
-        use_pallas = cfg.use_pallas
-        l0_slots = cfg.l0_slots
-        fanout = cfg.fanout
         self.mem_cap = cfg.memtable_cap or max(
             cfg.batch_cap * 4, min(cfg.capacity_per_shard, 1 << 18))
         self._closed = False
@@ -392,21 +349,21 @@ class ShardedTable:
         self._c_full_scans = self._reg.counter("db_full_scans", table=name)
         self._c_shard_ingest = [
             self._reg.counter("db_ingest_entries", table=name, shard=s)
-            for s in range(num_shards)]
+            for s in range(self.S)]
         self._c_shard_query = [
             self._reg.counter("db_point_queries", table=name, shard=s)
-            for s in range(num_shards)]
+            for s in range(self.S)]
         self._c_shard_scan = [
             self._reg.counter("db_range_scans", table=name, shard=s)
-            for s in range(num_shards)]
+            for s in range(self.S)]
         self._h_shard_query = [
             self._reg.histogram("db_shard_op_latency_s", table=name,
                                 shard=s, op="query")
-            for s in range(num_shards)]
+            for s in range(self.S)]
         self._h_shard_scan = [
             self._reg.histogram("db_shard_op_latency_s", table=name,
                                 shard=s, op="scan")
-            for s in range(num_shards)]
+            for s in range(self.S)]
         self._c_tablet_splits = self._reg.counter("lsm_tablet_splits",
                                                   table=name)
         self._c_tablet_moves = self._reg.counter("lsm_tablet_moves",
@@ -420,64 +377,31 @@ class ShardedTable:
                      + self._c_shard_scan + self._h_shard_query
                      + self._h_shard_scan):
             inst.reset()
-        if engine == "lsm":
-            from .lsm.bloom import BITS_PER_KEY, NUM_HASHES
-            from .lsm.engine import LSMRuns
-            self._runs = LSMRuns(
-                num_shards, capacity_per_shard, self.mem_cap, combiner,
-                use_pallas, l0_slots=l0_slots, fanout=fanout,
-                bloom_bits_per_key=(BITS_PER_KEY if bloom_bits_per_key is None
-                                    else bloom_bits_per_key),
-                bloom_hashes=(NUM_HASHES if bloom_hashes is None
-                              else bloom_hashes),
-                id_capacity=id_capacity, name=name)
-            self.tablets = None
-            self._ctr_single = None
-        else:
-            self._runs = None
-            self.tablets = jax.tree.map(
-                lambda *xs: jnp.stack(xs),
-                *[tablet_empty(self.cap)] * num_shards)
-            # same counter schema as the LSM engine (zeros where an op
-            # doesn't apply) so A/B stats line up — satellite of ISSUE 6
-            from .lsm.engine import STAT_KEYS
-            self._ctr_single = {
-                k: self._reg.counter("lsm_" + k, table=name)
-                for k in STAT_KEYS}
-            self._c_shard_flush_single = [
-                self._reg.counter("lsm_shard_flushes", table=name, shard=s)
-                for s in range(num_shards)]
-            self._h_flush_single = self._reg.histogram(
-                "db_op_latency_s", table=name, op="flush")
-            # retrace/write-amp series parity with the LSM engine (always
-            # zero here: the legacy path has no tracked fused builders)
-            self._ctr_single_extra = [
-                self._reg.counter("lsm_retraces", table=name, op="query"),
-                self._reg.counter("lsm_retraces", table=name, op="scan"),
-                self._reg.counter("lsm_flush_entries", table=name),
-                self._reg.counter("lsm_compact_entries", table=name)]
-            for inst in (list(self._ctr_single.values())
-                         + self._c_shard_flush_single
-                         + self._ctr_single_extra
-                         + [self._h_flush_single]):
-                inst.reset()
-        self._mem_r = jnp.full((num_shards, self.mem_cap), I32_MAX, jnp.int32)
-        self._mem_c = jnp.full((num_shards, self.mem_cap), I32_MAX, jnp.int32)
-        self._mem_v = jnp.zeros((num_shards, self.mem_cap), jnp.float32)
-        self._mem_n = np.zeros((num_shards,), np.int64)
+        from .lsm.bloom import BITS_PER_KEY, NUM_HASHES
+        from .lsm.engine import LSMRuns
+        self._runs = LSMRuns(
+            cfg.num_shards, cfg.capacity_per_shard, self.mem_cap, combiner,
+            cfg.use_pallas, l0_slots=cfg.l0_slots, fanout=cfg.fanout,
+            bloom_bits_per_key=(BITS_PER_KEY if bloom_bits_per_key is None
+                                else bloom_bits_per_key),
+            bloom_hashes=(NUM_HASHES if bloom_hashes is None
+                          else bloom_hashes),
+            id_capacity=cfg.id_capacity, name=name)
+        self._mem_r = jnp.full((self.S, self.mem_cap), I32_MAX, jnp.int32)
+        self._mem_c = jnp.full((self.S, self.mem_cap), I32_MAX, jnp.int32)
+        self._mem_v = jnp.zeros((self.S, self.mem_cap), jnp.float32)
+        self._mem_n = np.zeros((self.S,), np.int64)
         # host mirror of memtable appends (per shard): LSM reads serve the
         # unflushed tail without pulling device buffers. insert_routed()
         # bypasses the host, which invalidates the mirror until next flush.
-        self._mem_mirror = [[] for _ in range(num_shards)]
+        self._mem_mirror = [[] for _ in range(self.S)]
         self._mirror_ok = True
         # (row, col)-sorted + combiner-deduped mirror per shard, computed
         # lazily for the fused read path (saves an in-dispatch sort) and
         # reused until the next insert touches the shard
         self._mem_sorted: dict = {}
-        self._insert = _vmapped_insert(combiner, use_pallas)
         self._append = _APPEND
         self._append_flat = _APPEND_FLAT
-        self._shard_views: dict = {}  # per-shard tablet slices (read cache)
         self._wal = None
         self._wal_dir = None
         self._wal_ckpt_offset = 0
@@ -487,8 +411,6 @@ class ShardedTable:
     # ------------------------------------------------------- durability
     def attach_wal(self, wal_dir: str):
         """Open (or re-open) the write-ahead log under ``wal_dir``."""
-        if self.engine != "lsm":
-            raise ValueError("WAL durability requires engine='lsm'")
         import os
         from .lsm.manifest import wal_path
         from .lsm.wal import WriteAheadLog
@@ -504,8 +426,8 @@ class ShardedTable:
     def checkpoint(self) -> str:
         """Flush the memtable, snapshot the runs, mark the WAL offset.
         Returns the manifest path; ``db.lsm.recover`` consumes it."""
-        if self.engine != "lsm" or self._wal_dir is None:
-            raise ValueError("checkpoint() needs engine='lsm' and a wal_dir")
+        if self._wal_dir is None:
+            raise ValueError("checkpoint() needs a wal_dir")
         from .lsm.manifest import write_snapshot
         self.flush()
         path = write_snapshot(self, self._wal_dir)
@@ -522,10 +444,8 @@ class ShardedTable:
         if self.t_store is not None:
             self.t_store.close()
         self._runs = None
-        self.tablets = None
         self._mem_r = self._mem_c = self._mem_v = None
         self._mem_n = np.zeros((self.S,), np.int64)
-        self._shard_views.clear()
         self._closed = True
 
     def _check_open(self):
@@ -536,11 +456,7 @@ class ShardedTable:
         """Precompile the flush/compaction graphs (no state mutation) so
         benchmark windows measure steady-state throughput, not jit time."""
         self._check_open()
-        if self.engine == "lsm":
-            self._runs.warmup(self._mem_r, self._mem_c, self._mem_v)
-        else:
-            jax.block_until_ready(self._insert(
-                self.tablets, self._mem_r, self._mem_c, self._mem_v))
+        self._runs.warmup(self._mem_r, self._mem_c, self._mem_v)
         if self.t_store is not None:
             self.t_store.warmup()
 
@@ -548,80 +464,55 @@ class ShardedTable:
         """Precompile the read path's static serving shapes against the
         CURRENT resident state (runs/levels/memtable geometry is baked
         into the fused query graph, so this must run at serving time, not
-        ingest time). The LSM fused path has exactly two shapes — the
-        point bucket and the ``fused_q_limit`` query tile — and the tile
-        serves EVERY batch size, so one warm call here means no novel
-        batch size ever retraces. The legacy engine has no
-        batch-size-independent query shape to warm (its shape follows the
-        batch; a fresh size always recompiles) — for it this warms only a
-        nominal point batch. That asymmetry is the tiled-read claim.
-        Queries probe spread-out absent ids: every shard dispatches, and
-        ``lax.cond`` bloom gates compile both branches at trace time."""
+        ingest time). The fused path has exactly two shapes — the point
+        bucket and the ``fused_q_limit`` query tile — and the tile serves
+        EVERY batch size, so one warm call here means no novel batch size
+        ever retraces. Queries probe spread-out absent ids: every shard
+        dispatches, and ``lax.cond`` bloom gates compile both branches at
+        trace time."""
         self._check_open()
         self.query_rows(np.zeros(1, np.int32))  # point bucket
-        if self.engine == "lsm" and self.fused_reads:
-            if self.tablet_map is not None:
-                # skew-aware probe: a split/moved map can hand a shard a
-                # NARROW slice of the id space — a uniform linspace would
-                # give it <= 8 ids (point-bucket shape only) and the tile
-                # would compile lazily on the first real batch. Sample
-                # each shard's OWNED ranges instead, so both serving
-                # shapes re-warm after every topology change.
-                parts = [self.tablet_map.sample_shard_ids(s)
-                         for s in range(self.S)]
-                parts = [p for p in parts if len(p)]
-                probe = (np.concatenate(parts) if parts
-                         else np.zeros(1, np.int32))
-            else:
-                probe = np.linspace(0, self.id_capacity - 1,
-                                    2 * self.S * 8 + 2).astype(np.int32)
-            self.query_rows(np.unique(probe))   # > 8 ids/shard: the tile
+        if self.tablet_map is not None:
+            # skew-aware probe: a split/moved map can hand a shard a
+            # NARROW slice of the id space — a uniform linspace would
+            # give it <= 8 ids (point-bucket shape only) and the tile
+            # would compile lazily on the first real batch. Sample each
+            # shard's OWNED ranges instead, so both serving shapes
+            # re-warm after every topology change.
+            parts = [self.tablet_map.sample_shard_ids(s)
+                     for s in range(self.S)]
+            parts = [p for p in parts if len(p)]
+            probe = (np.concatenate(parts) if parts
+                     else np.zeros(1, np.int32))
+        else:
+            probe = np.linspace(0, self.id_capacity - 1,
+                                2 * self.S * 8 + 2).astype(np.int32)
+        self.query_rows(np.unique(probe))   # > 8 ids/shard: the tile
         if self.t_store is not None:  # column selectors serve from A^T
             self.t_store.warm_reads()
 
     def engine_stats(self) -> dict:
-        """Observability: flush/compaction counts and bloom skip rates.
-        Both engines emit the SAME counter schema (the single-run engine
-        reports zeros where an op doesn't apply) so A/B comparisons in
-        BENCH_ingest.json line up."""
-        if self.engine == "lsm":
-            st = dict(self._runs.stats)
-            st["l0_used"] = [int(x) for x in self._runs.l0_used]
-            st["level_entries"] = [int(lv["n"].sum())
-                                   for lv in self._runs.levels]
-            return st
-        st = {k: int(c.value) for k, c in self._ctr_single.items()}
-        st["l0_used"] = [0] * self.S
-        st["level_entries"] = []
+        """Observability: the engine counters (``lsm.engine.STAT_KEYS``)
+        plus per-shard used L0 slots and per-level resident entries."""
+        st = dict(self._runs.stats)
+        st["l0_used"] = [int(x) for x in self._runs.l0_used]
+        st["level_entries"] = [int(lv["n"].sum())
+                               for lv in self._runs.levels]
         return st
 
     def refresh_health_gauges(self, bloom_probes: int = 0) -> None:
         """Recompute the derived health gauges for this table (and its
         transpose sibling): memtable occupancy per shard, WAL backlog,
-        and — on the LSM engine — resident runs, compaction debt,
-        read/write amplification, and (``bloom_probes > 0``) the
-        observed-vs-theoretical bloom fp rate."""
+        resident runs, compaction debt, read/write amplification, and
+        (``bloom_probes > 0``) the observed-vs-theoretical bloom fp
+        rate."""
         self._check_open()
         for s in range(self.S):
             self._reg.gauge("db_memtable_occupancy", table=self.name,
                             shard=s).set(int(self._mem_n[s]) / self.mem_cap)
         if self._wal is not None:
             self._wal.refresh_backlog_gauge(self._wal_ckpt_offset)
-        if self.engine == "lsm":
-            self._runs.refresh_health_gauges(bloom_probes=bloom_probes)
-        else:
-            # series parity with the LSM engine: one sorted run per shard
-            # once flushed, never any compaction debt
-            n_host = np.asarray(self.tablets.n)
-            for s in range(self.S):
-                self._reg.gauge("lsm_resident_runs", table=self.name,
-                                shard=s).set(int(n_host[s] > 0))
-                self._reg.gauge("lsm_compaction_debt_entries",
-                                table=self.name, shard=s).set(0)
-            self._reg.gauge("lsm_read_amplification",
-                            table=self.name).set(0.0)
-            self._reg.gauge("lsm_write_amplification",
-                            table=self.name).set(0.0)
+        self._runs.refresh_health_gauges(bloom_probes=bloom_probes)
         if self.tablet_map is not None:
             self._reg.gauge("lsm_tablets", table=self.name).set(
                 self.tablet_map.n)
@@ -632,10 +523,7 @@ class ShardedTable:
 
     def nnz(self) -> int:
         self._check_open()
-        if self.engine == "lsm":
-            return sum(len(self.scan_shard(s)[0]) for s in range(self.S))
-        self.flush()
-        return int(self.tablets.n.sum())
+        return sum(len(self.scan_shard(s)[0]) for s in range(self.S))
 
     # ------------------------------------------------------------- ingest
     def route(self, rows: np.ndarray, cols: np.ndarray, vals: np.ndarray):
@@ -720,7 +608,7 @@ class ShardedTable:
         if (self._mem_n + counts_b > self.mem_cap).any():
             self.flush()
         ends = np.cumsum(counts_b)
-        if self.engine == "lsm" and self._mirror_ok:  # only LSM reads it
+        if self._mirror_ok:
             starts_m = ends - counts_b
             for s in np.nonzero(counts_b)[0]:
                 self._mem_mirror[s].append(
@@ -764,27 +652,12 @@ class ShardedTable:
         self._mem_n = np.asarray(counts, np.int64)
 
     def flush(self) -> None:
-        """Minor compaction: memtable -> L0 run (LSM, O(memtable)) or merge
-        into the single sorted run (legacy, O(capacity))."""
+        """Minor compaction: memtable -> one L0 run per shard,
+        O(memtable)."""
         self._check_open()
         if self._mem_n.max(initial=0) == 0:
             return
-        if self.engine == "lsm":
-            self._runs.flush_memtable(self._mem_r, self._mem_c, self._mem_v)
-        else:
-            with self._trace.span("flush", self._h_flush_single,
-                                  table=self.name):
-                new = self._insert(self.tablets, self._mem_r, self._mem_c,
-                                   self._mem_v)
-                if int(new.n.max()) > self.cap:
-                    raise OverflowError(
-                        f"tablet overflow in {self.name}: "
-                        f"{int(new.n.max())} > {self.cap}")
-                self.tablets = new
-            self._shard_views.clear()
-            self._ctr_single["flushes"].inc()
-            for s in np.nonzero(self._mem_n)[0]:
-                self._c_shard_flush_single[s].inc()
+        self._runs.flush_memtable(self._mem_r, self._mem_c, self._mem_v)
         self._mem_r = jnp.full((self.S, self.mem_cap), I32_MAX, jnp.int32)
         self._mem_c = jnp.full((self.S, self.mem_cap), I32_MAX, jnp.int32)
         self._mem_v = jnp.zeros((self.S, self.mem_cap), jnp.float32)
@@ -825,10 +698,8 @@ class ShardedTable:
         return got
 
     def major_compact(self) -> None:
-        """Force a major compaction (LSM): flush, then merge all runs."""
+        """Force a major compaction: flush, then merge all runs."""
         self._check_open()
-        if self.engine != "lsm":
-            return
         self.flush()
         self._runs.major_compact()
         if self.t_store is not None:
@@ -1017,26 +888,33 @@ class ShardedTable:
             tm.merge(int(op["tablet"]))
 
     # -------------------------------------------------------------- query
+    def _mem_fused(self, s: int):
+        """Shard ``s``'s unflushed tail for a fused read, as
+        ``(mem_host, mem_sorted)``: None when empty; the sorted, combined
+        host mirror when it is current; else lazy slices of the device
+        buffers (``insert_routed`` left the mirror stale), which the
+        dispatch sorts itself."""
+        mem_n = int(self._mem_n[s])
+        if mem_n == 0:
+            return None, False
+        if self._mirror_ok:
+            return self._mem_host_sorted(s), True
+        return (self._mem_r[s, :mem_n], self._mem_c[s, :mem_n],
+                self._mem_v[s, :mem_n]), False
+
     def query_rows(self, row_ids: np.ndarray, max_return: int = 256,
                    col_filter: np.ndarray = None):
         """Point queries; returns (row_id, col_id, val) numpy triples.
 
-        LSM engine: served from memtable + runs (bloom/fence read path) —
-        point reads never trigger a flush. Legacy engine: flushes only when
-        a QUERIED shard's memtable is non-empty (read-your-writes without
-        the old unconditional global flush).
-
-        ``col_filter`` restricts results to the given column id set; on
-        the fused LSM path the membership test runs ON DEVICE inside the
-        dispatch (no host post-filter), other paths filter on the host.
+        Served from memtable + runs by one fused dispatch per shard and
+        query tile (bloom/fence read path) — point reads never trigger a
+        flush. ``col_filter`` restricts results to the given column id
+        set; the membership test runs ON DEVICE inside the dispatch.
         """
         self._check_open()
         t_call = perf_counter()
-        host_filter = None
         if col_filter is not None:
             col_filter = np.asarray(col_filter, np.int32)
-            if not (self.engine == "lsm" and self.fused_reads):
-                host_filter, col_filter = col_filter, None
         row_ids = np.asarray(row_ids, np.int32)
         if self.tablet_map is not None:
             tidx = self.tablet_map.tablet_of(row_ids)
@@ -1045,89 +923,36 @@ class ShardedTable:
         else:
             owner = shard_of(row_ids, self.S, self.id_capacity)
         out_r, out_c, out_v = [], [], []
-        if self.engine == "lsm":
-            for s in np.unique(owner):
-                q = row_ids[owner == s]
-                self._c_shard_query[int(s)].inc(len(q))
-                t_sh = perf_counter()
-                # duplicate query ids return duplicate results (legacy-
-                # engine parity): query unique ids, then re-expand
-                uq, ucnt = np.unique(q, return_counts=True)
-                mem_n = int(self._mem_n[s])
-                mh = self._mem_host(int(s))
-                if self.fused_reads:
-                    mem_sorted = False
-                    if mem_n == 0:
-                        fmem = None
-                    elif mh is not None:
-                        fmem = self._mem_host_sorted(int(s))
-                        mem_sorted = True
-                    else:  # mirror stale: slice device buffers (lazy)
-                        fmem = (self._mem_r[s, :mem_n],
-                                self._mem_c[s, :mem_n],
-                                self._mem_v[s, :mem_n])
-                    if fmem is None and not self._runs.resident_runs(int(s)):
-                        # empty shard: nothing to dispatch — still observed
-                        self._h_shard_query[int(s)].observe(
-                            perf_counter() - t_sh)
-                        continue
-                    r, c, v = self._runs.query_shard_fused(
-                        int(s), uq, mem_host=fmem, max_return=max_return,
-                        mem_sorted=mem_sorted, q_tile=self.fused_q_limit,
-                        col_filter=col_filter)
-                else:
-                    if mh is None and mem_n:  # stale: pull device bufs
-                        mem = (self._mem_r[s], self._mem_c[s],
-                               self._mem_v[s])
-                    else:
-                        mem = (None, None, None)
-                    r, c, v = self._runs.query_shard(
-                        int(s), uq, *mem, mem_n, max_return, mem_host=mh)
-                if len(r) and (ucnt > 1).any():
-                    rep = ucnt[np.searchsorted(uq, r)]
-                    r, c, v = (np.repeat(r, rep), np.repeat(c, rep),
-                               np.repeat(v, rep))
-                self._h_shard_query[int(s)].observe(perf_counter() - t_sh)
-                out_r.append(r); out_c.append(c); out_v.append(v)
-        else:
-            owners = np.unique(owner)
-            if self._mem_n[owners].max(initial=0) > 0:
-                self.flush()
-            for s in owners:
-                q = row_ids[owner == s]
-                self._c_shard_query[int(s)].inc(len(q))
-                t_sh = perf_counter()
-                t = self._shard_views.get(int(s))
-                if t is None:  # slicing stacked arrays copies ~MBs; cache it
-                    t = jax.tree.map(lambda x: x[s], self.tablets)
-                    self._shard_views[int(s)] = t
-                cols, vals, ok, cnt = tablet_query_rows(
-                    t, jnp.asarray(q), max_return,
-                    use_pallas=self.use_pallas)
-                cnt = np.asarray(cnt)
-                if cnt.max(initial=0) > max_return:  # widen (batch scanner)
-                    cols, vals, ok, cnt = tablet_query_rows(
-                        t, jnp.asarray(q), int(cnt.max()),
-                        use_pallas=self.use_pallas)
-                ok = np.asarray(ok)
-                cols, vals = np.asarray(cols), np.asarray(vals)
-                qi, ki = np.nonzero(ok)
-                self._h_shard_query[int(s)].observe(perf_counter() - t_sh)
-                out_r.append(q[qi])
-                out_c.append(cols[qi, ki])
-                out_v.append(vals[qi, ki])
+        for s in np.unique(owner):
+            s = int(s)
+            q = row_ids[owner == s]
+            self._c_shard_query[s].inc(len(q))
+            t_sh = perf_counter()
+            # duplicate query ids return duplicate results: query unique
+            # ids, then re-expand
+            uq, ucnt = np.unique(q, return_counts=True)
+            fmem, mem_sorted = self._mem_fused(s)
+            if fmem is None and not self._runs.resident_runs(s):
+                # empty shard: nothing to dispatch — still observed
+                self._h_shard_query[s].observe(perf_counter() - t_sh)
+                continue
+            r, c, v = self._runs.query_shard_fused(
+                s, uq, self.fused_q_limit, mem_host=fmem,
+                max_return=max_return, mem_sorted=mem_sorted,
+                col_filter=col_filter)
+            if len(r) and (ucnt > 1).any():
+                rep = ucnt[np.searchsorted(uq, r)]
+                r, c, v = (np.repeat(r, rep), np.repeat(c, rep),
+                           np.repeat(v, rep))
+            self._h_shard_query[s].observe(perf_counter() - t_sh)
+            out_r.append(r); out_c.append(c); out_v.append(v)
         if len(row_ids):
             self._h_query.observe(perf_counter() - t_call)
         if not out_r:
             z = np.zeros(0, np.int32)
             return z, z.copy(), np.zeros(0, np.float32)
-        r = np.concatenate(out_r)
-        c = np.concatenate(out_c)
-        v = np.concatenate(out_v)
-        if host_filter is not None:  # non-fused paths: filter on the host
-            keep = np.isin(c, host_filter)
-            r, c, v = r[keep], c[keep], v[keep]
-        return r, c, v
+        return (np.concatenate(out_r), np.concatenate(out_c),
+                np.concatenate(out_v))
 
     def scan_range(self, lo: int, hi: int, width: int = 64,
                    col_filter: np.ndarray = None):
@@ -1135,23 +960,15 @@ class ShardedTable:
         sorted lex by (row, col) per shard — the server-side analogue of an
         Accumulo tablet range scan.
 
-        LSM + ``fused_reads``: each overlapping shard is answered by ONE
-        fused fence-to-fence dispatch (``scan_shard_fused``) — no id-list
-        point expansion. With ``fused_reads`` off the per-shard full scan
-        is filtered on the host (the A/B baseline); the legacy single-run
-        engine flushes and slices its sorted run by the endpoint ranks.
-
-        ``col_filter`` restricts results to the given column id set; the
-        fused path masks on-device inside the scan dispatch, other paths
-        filter on the host."""
+        Each overlapping shard is answered by ONE fused fence-to-fence
+        dispatch (``scan_shard_fused``) — no id-list point expansion.
+        ``col_filter`` restricts results to the given column id set,
+        masked on-device inside the scan dispatch."""
         self._check_open()
         t_call = perf_counter()
         lo, hi = int(lo), int(hi)
-        host_filter = None
         if col_filter is not None:
             col_filter = np.asarray(col_filter, np.int32)
-            if not (self.engine == "lsm" and self.fused_reads):
-                host_filter, col_filter = col_filter, None
         out_r, out_c, out_v = [], [], []
         if hi > lo:
             if self.tablet_map is not None:
@@ -1167,45 +984,13 @@ class ShardedTable:
                                     self.id_capacity)[0])
                 # each shard clips the full range itself (fence ranks)
                 segs = [(s, lo, hi) for s in range(s_lo, s_hi + 1)]
-            if self.engine != "lsm":
-                if self._mem_n[[s for s, _, _ in segs]].max(initial=0) > 0:
-                    self.flush()
             for s, seg_lo, seg_hi in segs:
                 self._c_shard_scan[s].inc()
                 t_sh = perf_counter()
-                if self.engine == "lsm":
-                    mem_n = int(self._mem_n[s])
-                    mh = self._mem_host(s)
-                    if self.fused_reads:
-                        mem_sorted = False
-                        if mem_n == 0:
-                            fmem = None
-                        elif mh is not None:
-                            fmem = self._mem_host_sorted(int(s))
-                            mem_sorted = True
-                        else:  # mirror stale: slice device buffers (lazy)
-                            fmem = (self._mem_r[s, :mem_n],
-                                    self._mem_c[s, :mem_n],
-                                    self._mem_v[s, :mem_n])
-                        r, c, v = self._runs.scan_shard_fused(
-                            int(s), seg_lo, seg_hi, mem_host=fmem,
-                            width=width, mem_sorted=mem_sorted,
-                            col_filter=col_filter)
-                    else:  # baseline: full shard scan + host range filter
-                        r, c, v = self.scan_shard(s)
-                        keep = (r >= seg_lo) & (r < seg_hi)
-                        r, c, v = r[keep], c[keep], v[keep]
-                else:  # legacy single run: endpoint ranks on the host copy
-                    t = self._shard_views.get(int(s))
-                    if t is None:
-                        t = jax.tree.map(lambda x: x[s], self.tablets)
-                        self._shard_views[int(s)] = t
-                    rows = np.asarray(t.rows)
-                    a = int(np.searchsorted(rows, seg_lo, side="left"))
-                    b = int(np.searchsorted(rows, seg_hi, side="left"))
-                    r = rows[a:b]
-                    c = np.asarray(t.cols)[a:b]
-                    v = np.asarray(t.vals)[a:b]
+                fmem, mem_sorted = self._mem_fused(s)
+                r, c, v = self._runs.scan_shard_fused(
+                    s, seg_lo, seg_hi, mem_host=fmem, width=width,
+                    mem_sorted=mem_sorted, col_filter=col_filter)
                 self._h_shard_scan[s].observe(perf_counter() - t_sh)
                 if len(r):
                     out_r.append(r); out_c.append(c); out_v.append(v)
@@ -1213,13 +998,8 @@ class ShardedTable:
         if not out_r:
             z = np.zeros(0, np.int32)
             return z, z.copy(), np.zeros(0, np.float32)
-        r = np.concatenate(out_r)
-        c = np.concatenate(out_c)
-        v = np.concatenate(out_v)
-        if host_filter is not None:  # non-fused paths: filter on the host
-            keep = np.isin(c, host_filter)
-            r, c, v = r[keep], c[keep], v[keep]
-        return r, c, v
+        return (np.concatenate(out_r), np.concatenate(out_c),
+                np.concatenate(out_v))
 
     # ------------------------------------------------ column-axis reads
     def query_cols(self, col_ids: np.ndarray, max_return: int = 256):
@@ -1251,10 +1031,8 @@ class ShardedTable:
         return tc, tr, tv  # sibling rows ARE our cols (and vice versa)
 
     def scan_shard(self, s: int):
-        """One shard's combined sorted triples (LSM; no flush)."""
+        """One shard's combined sorted triples (no flush)."""
         self._check_open()
-        if self.engine != "lsm":
-            raise ValueError("scan_shard() requires engine='lsm'")
         mem_n = int(self._mem_n[s])
         mh = self._mem_host(s)
         if mh is None and mem_n:
@@ -1267,15 +1045,7 @@ class ShardedTable:
         """Full-table scan -> (row_ids, col_ids, vals), sorted per shard."""
         self._check_open()
         self._c_full_scans.inc()
-        if self.engine == "lsm":
-            parts = [self.scan_shard(s) for s in range(self.S)]
-            return (np.concatenate([p[0] for p in parts]),
-                    np.concatenate([p[1] for p in parts]),
-                    np.concatenate([p[2] for p in parts]))
-        self.flush()
-        rows = np.asarray(self.tablets.rows)
-        cols = np.asarray(self.tablets.cols)
-        vals = np.asarray(self.tablets.vals)
-        n = np.asarray(self.tablets.n)
-        keep = np.arange(rows.shape[1])[None, :] < n[:, None]
-        return rows[keep], cols[keep], vals[keep]
+        parts = [self.scan_shard(s) for s in range(self.S)]
+        return (np.concatenate([p[0] for p in parts]),
+                np.concatenate([p[1] for p in parts]),
+                np.concatenate([p[2] for p in parts]))

@@ -18,22 +18,20 @@ level — deep levels absorb most negative lookups) and fence pointers
 any flush/compaction schedule because every merge preserves age order
 within equal-key groups and every dedup applies the same combiner.
 
-Two read paths serve point queries (neither ever flushes):
-
-* **fused** (default, ``query_shard_fused``): the entire shard — every
-  leveled run, the whole L0 stack, and the memtable tail — is searched by
-  ONE jitted dispatch per query TILE. Runs keep their static stacked
-  shapes (levels are distinct-capacity buckets, L0 is already a [K0, m]
-  batch; empty slots are inert I32_MAX padding, so no re-bucketing is
-  ever needed), each run's fence-bracketed rank search is block
-  bloom-gated (``lax.cond`` — a tile that misses a run's filter skips its
-  probe entirely), and the cross-run age-ordered combine happens
-  on-device via the batched ``merge_rank`` rank+scatter merge. Batches
-  larger than the tile split into fixed-size blocks that reuse ONE jit
-  cache entry: ceil(Q/tile) dispatches, never a per-run fallback.
-* **per-run** (``query_shard``): one bloom-gated kernel launch per
-  resident run, combined on the host. Kept as the A/B baseline
-  (``fused_reads=False``) and for the stale-mirror recovery corners.
+Reads never flush. A point query (``query_shard_fused``) searches the
+entire shard — every leveled run, the whole L0 stack, and the memtable
+tail — in ONE jitted dispatch per query TILE. Runs keep their static
+stacked shapes (levels are distinct-capacity buckets, L0 is already a
+[K0, m] batch; empty slots are inert I32_MAX padding, so no re-bucketing
+is ever needed), each run's fence-bracketed rank search is block
+bloom-gated (``lax.cond`` — a tile that misses a run's filter skips its
+probe entirely), and the cross-run age-ordered combine happens on-device
+via the batched ``merge_rank`` rank+scatter merge. Batches larger than
+the tile split into fixed-size blocks that reuse ONE jit cache entry:
+ceil(Q/tile) dispatches. A row-range scan (``scan_shard_fused``) is one
+fence-to-fence dispatch per shard. A memtable tail whose host mirror is
+stale (device-side appends) is handed over as device slices and sorted
+inside the same dispatch.
 
 All state is stacked [S, ...] across shards. A flush is vmapped over the
 S simulated tablet servers; a major compaction is one dispatch that visits
@@ -103,7 +101,7 @@ def _bucket(n: int, lo: int = 8) -> int:
 def _sort_dedup(r, c, v, combiner: str):
     """Sort one buffer lex by (row, col) (stable → age order kept), apply
     the combiner, compact valid entries to the front. Returns (r, c, v, n)."""
-    from ..kvstore import _dedup_combine  # shared with the legacy engine
+    from ..kvstore import _dedup_combine  # shared with tablet_insert, db.spmd
 
     cap = r.shape[0]
     sr, sc, sv = jax.lax.sort((r, c, v), num_keys=2, is_stable=True)
@@ -217,57 +215,6 @@ def _compact_fn(combiner: str, use_pallas: bool, out_cap: int, n_words: int,
         return jax.lax.map(shard, (l0_r, l0_c, l0_v, lvls))
 
     return jax.jit(lsm_compact)
-
-
-@functools.partial(jax.jit, static_argnames=("max_return", "block"))
-def run_query_rows(rows, cols, vals, fence, q, max_return: int, block: int):
-    """Fence-bracketed point row query against one sorted run.
-
-    The fence array (block-start row ids) locates the block holding each
-    query's start/end rank; the exact rank search then touches only that
-    block (+1 entry of spill) — the in-memory analogue of reading a single
-    index-addressed RFile block. Returns (cols[Q, max_return],
-    vals[Q, max_return], ok[Q, max_return], counts[Q]).
-    """
-    cap = rows.shape[0]
-    w = block + 1
-
-    def bracketed(qi, side):
-        fi = jnp.searchsorted(fence, qi, side=side)
-        base = jnp.clip(jnp.maximum(fi - 1, 0) * block, 0, cap - w)
-        win = jax.lax.dynamic_slice(rows, (base,), (w,))
-        return (base + jnp.searchsorted(win, qi, side=side)).astype(jnp.int32)
-
-    start = jax.vmap(lambda qi: bracketed(qi, "left"))(q)
-    end = jax.vmap(lambda qi: bracketed(qi, "right"))(q)
-    idx = start[:, None] + jnp.arange(max_return, dtype=jnp.int32)[None, :]
-    ok = idx < end[:, None]
-    idxc = jnp.clip(idx, 0, cap - 1)
-    return cols[idxc], vals[idxc], ok, end - start
-
-
-@functools.partial(jax.jit, static_argnames=("max_return", "block", "n_hashes"))
-def run_query_gated(rows, cols, vals, fence, bloom, q, max_return: int,
-                    block: int, n_hashes: int = NUM_HASHES):
-    """Bloom-gated run query in ONE dispatch: probe the bloom filter and,
-    only when some queried row may be present (lax.cond — the search branch
-    is genuinely skipped otherwise), run the fence-bracketed rank search.
-    Returns (any_hit, cols, vals, ok, counts). The per-run baseline path
-    launches these for every run back-to-back and syncs once; the fused
-    path replaces the N launches with one."""
-    any_hit = jnp.any(bloom_maybe_contains(bloom, q, n_hashes))
-
-    def probe(_):
-        return run_query_rows(rows, cols, vals, fence, q, max_return, block)
-
-    def skip(_):
-        nq = q.shape[0]
-        return (jnp.zeros((nq, max_return), jnp.int32),
-                jnp.zeros((nq, max_return), jnp.float32),
-                jnp.zeros((nq, max_return), jnp.bool_),
-                jnp.zeros((nq,), jnp.int32))
-
-    return (any_hit,) + jax.lax.cond(any_hit, probe, skip, None)
 
 
 # ----------------------------------------------------------- fused read path
@@ -687,11 +634,11 @@ def _prep_mem(mem_host: Optional[Tuple], mem_sorted: bool):
             jnp.pad(mv, (0, pad))), "raw"
 
 
-# counter schema shared by BOTH engines ("single" reports zeros where an
-# op doesn't apply) so A/B stats line up in BENCH_ingest.json
+# the engine's counter schema: each key is the registry counter
+# ``lsm_<key>{table}`` and a key of ``ShardedTable.engine_stats()``
 STAT_KEYS = ("flushes", "major_compactions", "runs_probed", "runs_skipped",
              "fused_dispatches", "fused_widen_retries", "fused_tiles",
-             "perrun_dispatches", "scan_dispatches", "scan_widen_retries")
+             "scan_dispatches", "scan_widen_retries")
 
 
 # ------------------------------------------------------------------ engine
@@ -810,7 +757,7 @@ class LSMRuns:
                      + self._c_shard_flush + self._c_shard_compact):
             inst.reset()
         # per-run sliced views of the stacked arrays (slicing copies ~MBs
-        # eagerly per query otherwise); invalidated on flush/compaction.
+        # eagerly per scan otherwise); invalidated on flush/compaction.
         # Fused-path entries key ("fused", s) and hold the level tuple +
         # L0 stack views handed to the single-dispatch query.
         self._view_cache: dict = {}
@@ -843,8 +790,7 @@ class LSMRuns:
         """Minor compaction: memtable -> one L0 run per shard, O(m log m).
         Shards whose OWN L0 is full (and that actually have data to flush)
         are major-compacted first — peers keep their L0 runs untouched.
-        May raise OverflowError (capacity back-pressure, like the legacy
-        engine)."""
+        May raise OverflowError (capacity back-pressure)."""
         with self._trace.span("flush", self._h_flush, table=self.name):
             self._flush_memtable(mem_r, mem_c, mem_v)
 
@@ -1060,8 +1006,7 @@ class LSMRuns:
             reg.gauge("lsm_compaction_debt_entries", table=self.name,
                       shard=s).set(int(self.l0_n[s, :u].sum()))
         c = self._ctr
-        reads = int(c["fused_dispatches"].value
-                    + c["perrun_dispatches"].value)
+        reads = int(c["fused_dispatches"].value)
         probed = int(c["runs_probed"].value)
         reg.gauge("lsm_read_amplification", table=self.name).set(
             probed / reads if reads else 0.0)
@@ -1113,40 +1058,33 @@ class LSMRuns:
         return tot_fp / tot_probes, theo_w / tot_probes
 
     def _iter_runs_oldest_first(self, s: int):
-        """Yield (rows, cols, vals, fence, bloom, n, block, minr, maxr,
-        hashes) per-run views of shard ``s``, oldest (deepest level) to
-        newest (latest L0 slot)."""
+        """Yield (rows, cols, vals, n) per-run views of shard ``s``, oldest
+        (deepest level) to newest (latest L0 slot)."""
         for i in range(len(self.levels) - 1, -1, -1):
             lv = self.levels[i]
             if lv["n"][s]:
                 key = ("lvl", i, s)
                 view = self._view_cache.get(key)
                 if view is None:
-                    view = (lv["rows"][s], lv["cols"][s], lv["vals"][s],
-                            lv["fence"][s], lv["bloom"][s])
+                    view = (lv["rows"][s], lv["cols"][s], lv["vals"][s])
                     self._view_cache[key] = view
-                yield view + (int(lv["n"][s]), lv["block"],
-                              int(lv["minr"][s]), int(lv["maxr"][s]),
-                              lv["hashes"])
+                yield view + (int(lv["n"][s]),)
         for k in range(int(self.l0_used[s])):
             if self.l0_n[s, k]:
                 key = ("l0", k, s)
                 view = self._view_cache.get(key)
                 if view is None:
                     view = (self.l0_rows[s, k], self.l0_cols[s, k],
-                            self.l0_vals[s, k], self.l0_fence[s, k],
-                            self.l0_bloom[s, k])
+                            self.l0_vals[s, k])
                     self._view_cache[key] = view
-                yield view + (int(self.l0_n[s, k]), self._b0,
-                              int(self.l0_min[s, k]), int(self.l0_max[s, k]),
-                              self._h0)
+                yield view + (int(self.l0_n[s, k]),)
 
     def _fused_views(self, s: int):
         """Per-shard stacked views for the fused dispatch: the RESIDENT
         leveled runs (deepest first, with their static fence-block/hash
-        meta) plus the L0 stack sliced to the used slots. Restricting the
-        dispatch to resident runs is what lets it beat the per-run path —
-        probing an empty 256k-capacity level costs real gather work.
+        meta) plus the L0 stack sliced to the used slots. The dispatch
+        probes resident runs only: probing an empty 256k-capacity level
+        costs real gather work.
         Residency only changes on flush/compaction, which is exactly when
         this cache invalidates, so the slicing cost is amortized across
         every query in between (no per-query re-bucketing)."""
@@ -1196,11 +1134,10 @@ class LSMRuns:
                 ci.currsize)
         return fn
 
-    def query_shard_fused(self, s: int, q: np.ndarray,
+    def query_shard_fused(self, s: int, q: np.ndarray, q_tile: int,
                           mem_host: Optional[Tuple] = None,
                           max_return: int = 256,
                           mem_sorted: bool = False,
-                          q_tile: Optional[int] = None,
                           col_filter: Optional[np.ndarray] = None):
         """Point row queries for one shard, fused: each dispatch searches
         the resident leveled runs, the used L0 slots, and the memtable
@@ -1209,21 +1146,19 @@ class LSMRuns:
         the shard's unflushed tail as (rows, cols, vals) arrays — numpy
         (host mirror; pass ``mem_sorted=True`` if already
         (row, col)-sorted and combiner-deduped) or device slices
-        (stale-mirror SPMD path). NO flush happens.
+        (stale mirror). NO flush happens.
 
-        When ``q_tile`` is set the read path serves every batch size from
-        exactly TWO static shapes: tiny point reads (n_q <= 8) use the
-        small bucket, and everything else pads UP to the ``q_tile`` tile —
-        batches larger than the tile split into ceil(n_q / tile)
-        dispatches of that one shape, each independently widen-retryable.
-        One jit cache entry therefore covers every large batch size the
-        caller ever sends (a fresh size never retraces — the legacy
-        engine, whose query shape follows the batch, recompiles per novel
-        size). Each run's probe is block bloom-gated inside the dispatch,
-        so a tile whose keys all miss a run's filter skips that run's
-        search entirely. Tiles are contiguous slices of the sorted ``q``,
-        so concatenating per-tile results preserves global row order.
-        ``q_tile=None`` keeps the legacy bucket-by-batch-size shapes.
+        Every batch size is served from exactly TWO static shapes: tiny
+        point reads (n_q <= 8) use the small bucket, and everything else
+        pads UP to the ``q_tile`` tile — batches larger than the tile
+        split into ceil(n_q / tile) dispatches of that one shape, each
+        independently widen-retryable. One jit cache entry therefore
+        covers every large batch size the caller ever sends (a fresh size
+        never retraces). Each run's probe is block bloom-gated inside the
+        dispatch, so a tile whose keys all miss a run's filter skips that
+        run's search entirely. Tiles are contiguous slices of the sorted
+        ``q``, so concatenating per-tile results preserves global row
+        order.
 
         ``col_filter`` (optional int32 id set) pushes the residual
         column ``isin`` of a row-driven read into the dispatch as an
@@ -1248,8 +1183,7 @@ class LSMRuns:
         # Qtile * (runs * width)^2, and point reads rarely exceed a few
         # entries per run — cnt_max triggers the widen retry when they do
         r_ret = min(4, _bucket(max_return))
-        tile = (_bucket(n_q) if q_tile is None or n_q <= 8
-                else _bucket(q_tile))
+        tile = _bucket(n_q) if n_q <= 8 else _bucket(q_tile)
         n_tiles = max(1, -(-n_q // tile))
         if n_tiles > 1:
             self._ctr["fused_tiles"].inc(n_tiles)
@@ -1379,79 +1313,13 @@ class LSMRuns:
         return (rows_s[ki].astype(np.int32), cols_s[ki].astype(np.int32),
                 vals_s[ki].astype(np.float32))
 
-    def query_shard(self, s: int, q: np.ndarray, mem_r, mem_c, mem_v,
-                    mem_n: int, max_return: int,
-                    mem_host: Optional[Tuple[np.ndarray, ...]] = None):
-        """Per-run baseline read path: probe runs oldest→newest plus the
-        memtable tail, one bloom-gated launch per resident run, combine
-        across sources on the host. NO flush happens.
-
-        Two-phase: launch the bloom-gated query of every candidate run
-        asynchronously, then sync once and harvest — latency is one device
-        round-trip but still N dispatches; ``query_shard_fused`` collapses
-        those into one. ``mem_host`` is an optional host mirror of the
-        shard's memtable (avoids pulling the device buffer)."""
-        q_dev = jnp.asarray(q)
-        q_sorted = np.sort(q)
-        launched = []
-        age = 0
-        for rows, cols, vals, fence, bloom, n, block, minr, maxr, hashes in \
-                self._iter_runs_oldest_first(s):
-            age += 1
-            if q_sorted[-1] < minr or q_sorted[0] > maxr:
-                self._ctr["runs_skipped"].inc()
-                continue
-            self._ctr["perrun_dispatches"].inc()
-            out = run_query_gated(rows, cols, vals, fence, bloom, q_dev,
-                                  max_return, block, hashes)
-            launched.append((age, (rows, cols, vals, fence, block), out))
-        cand_r, cand_c, cand_v, cand_a = [], [], [], []
-        for age_i, run, (any_hit, cols_o, vals_o, ok, cnt) in launched:
-            if not bool(any_hit):  # bloom says absent — search was skipped
-                self._ctr["runs_skipped"].inc()
-                continue
-            self._ctr["runs_probed"].inc()
-            cnt = np.asarray(cnt)
-            if cnt.max(initial=0) > max_return:  # widen + retry (scanner)
-                rows, cols, vals, fence, block = run
-                self._ctr["perrun_dispatches"].inc()
-                cols_o, vals_o, ok, cnt = run_query_rows(
-                    rows, cols, vals, fence, q_dev, int(cnt.max()), block)
-            ok = np.asarray(ok)
-            cols_o, vals_o = np.asarray(cols_o), np.asarray(vals_o)
-            qi, ki = np.nonzero(ok)
-            cand_r.append(q[qi]); cand_c.append(cols_o[qi, ki])
-            cand_v.append(vals_o[qi, ki])
-            cand_a.append(np.full(len(qi), age_i, np.int32))
-        if mem_n:
-            if mem_host is not None:
-                mr, mc, mv = mem_host
-            else:
-                mr = np.asarray(mem_r[:mem_n])
-                mc = np.asarray(mem_c[:mem_n])
-                mv = np.asarray(mem_v[:mem_n])
-            mask = np.isin(mr, q)
-            if mask.any():
-                cand_r.append(mr[mask])
-                cand_c.append(mc[mask])
-                cand_v.append(mv[mask])
-                cand_a.append(np.full(int(mask.sum()), age + 1, np.int32))
-        if not cand_r:
-            z = np.zeros(0, np.int32)
-            return z, z.copy(), np.zeros(0, np.float32)
-        return combine_triples(np.concatenate(cand_r).astype(np.int32),
-                               np.concatenate(cand_c).astype(np.int32),
-                               np.concatenate(cand_v).astype(np.float32),
-                               np.concatenate(cand_a), self.combiner)
-
     def scan_shard(self, s: int, mem_r, mem_c, mem_v, mem_n: int,
                    mem_host: Optional[Tuple[np.ndarray, ...]] = None):
         """All (row, col, val) of one shard, combined across runs + memtable,
         sorted lex by (row, col). NO flush happens."""
         cand = []
         age = 0
-        for rows, cols, vals, fence, bloom, n, block, minr, maxr, hashes in \
-                self._iter_runs_oldest_first(s):
+        for rows, cols, vals, n in self._iter_runs_oldest_first(s):
             age += 1
             cand.append((np.asarray(rows[:n]), np.asarray(cols[:n]),
                          np.asarray(vals[:n]),

@@ -49,7 +49,7 @@ def write_snapshot(table, dirpath: str) -> str:
     import dataclasses
 
     os.makedirs(dirpath, exist_ok=True)
-    runs = table._runs  # LSM engine only
+    runs = table._runs
     state = dict(runs.state_arrays())
     if table.t_store is not None:  # pair: sibling rides in the same npz
         for k, v in table.t_store._runs.state_arrays().items():
@@ -92,7 +92,7 @@ def write_snapshot(table, dirpath: str) -> str:
 
 
 def recover(dirpath: str, tablet_filter=None):
-    """Rebuild a ``ShardedTable`` (engine='lsm') after a crash.
+    """Rebuild a ``ShardedTable`` after a crash.
 
     Works from any consistent prefix of (manifest?, snapshot?, WAL): with no
     manifest the whole WAL replays into a table that must be given its
@@ -118,11 +118,10 @@ def recover(dirpath: str, tablet_filter=None):
         man = json.load(f)
     cfg = man["config"]
     table = ShardedTable(
-        man.get("name", "recovered"), engine="lsm",
-        combiner=cfg["combiner"],
+        man.get("name", "recovered"), combiner=cfg["combiner"],
         bloom_bits_per_key=tuple(cfg.get("bloom_bits_per_key", ())) or None,
         bloom_hashes=tuple(cfg.get("bloom_hashes", ())) or None,
-        config=StoreConfig.from_manifest(cfg).replace(engine="lsm"))
+        config=StoreConfig.from_manifest(cfg))
     snap = os.path.join(dirpath, man["snapshot"])
     if os.path.exists(snap):
         with np.load(snap) as z:

@@ -7,10 +7,9 @@ Everything here is read-only over a Registry/Tracer — exporting never
 mutates series, so it is safe to call from a signal handler, a bench
 epilogue, or a monitoring thread while the storage path is live.
 
-CLI (reads a registry dump produced by ``Registry.dump`` /
-``ingest_bench --metrics-out``):
+CLI (reads a registry dump produced by ``Registry.dump``):
 
-    python -m repro.obs.export --metrics METRICS_ingest.json            # md
+    python -m repro.obs.export --metrics M.json                         # md
     python -m repro.obs.export --metrics M.json --format term
     python -m repro.obs.export --metrics M.json --prometheus out.prom
 """
@@ -254,7 +253,7 @@ def write_debug_bundle(path: str, reg: Registry = None, tracer=None,
     """One-stop diagnostic archive (zip): registry snapshot + Prometheus
     text + slow traces / flight recordings, plus any `extra` sections
     (JSON-serializable, one member per key). This is the engine under
-    ``DBserver.debug_bundle`` and the bench debug-bundle artifact."""
+    ``DBserver.debug_bundle``."""
     reg = reg if reg is not None else default_registry()
     tracer = tracer if tracer is not None else default_tracer()
     with zipfile.ZipFile(path, "w", zipfile.ZIP_DEFLATED) as zf:

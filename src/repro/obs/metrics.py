@@ -7,7 +7,7 @@ Design constraints (ISSUE 6):
   * near-zero overhead when disabled: every mutator checks a single
     registry-level flag and returns immediately.
   * process-global default registry so instrumentation sites never need
-    plumbing; tests and benchmarks may build private registries.
+    plumbing; tests may build private registries.
 
 Histogram math: bucket edges grow by 2**(1/SUBBUCKETS) per bin (8
 sub-buckets per octave), so any sample's bucket representative (the

@@ -43,7 +43,7 @@ class WorkQueue:
 
     A slow ingestor simply claims fewer batches; a dead one (never acks)
     has its in-flight batch re-queued after ``timeout_batches`` pulls by
-    others. Used by benchmarks/ingest_bench.py --steal."""
+    others."""
 
     def __init__(self, batches, timeout_batches: int = 8):
         self.pending = list(range(len(batches)))

@@ -269,14 +269,13 @@ def test_recovery_truncates_torn_tail_so_new_writes_survive(tmp_path):
 
 
 def test_duplicate_query_ids_return_duplicate_results():
-    """Legacy-engine parity: query_rows([x, x]) yields x's entries twice."""
-    for engine in ("single", "lsm"):
-        st = ShardedTable("dup", num_shards=1, capacity_per_shard=256,
-                          batch_cap=64, id_capacity=1 << 10, engine=engine)
-        st.insert(np.asarray([7, 7], np.int32), np.asarray([1, 2], np.int32),
-                  np.asarray([1.0, 2.0], np.float32))
-        r, c, v = st.query_rows(np.asarray([7, 7], np.int32))
-        assert len(r) == 4, (engine, len(r))
+    """query_rows([x, x]) yields x's entries twice."""
+    st = ShardedTable("dup", num_shards=1, capacity_per_shard=256,
+                      batch_cap=64, id_capacity=1 << 10)
+    st.insert(np.asarray([7, 7], np.int32), np.asarray([1, 2], np.int32),
+              np.asarray([1.0, 2.0], np.float32))
+    r, c, v = st.query_rows(np.asarray([7, 7], np.int32))
+    assert len(r) == 4, len(r)
 
 
 # ------------------------------------------------------- connector delete
@@ -299,18 +298,6 @@ def test_delete_poisons_handle_and_frees_store():
     # re-binding the name creates a fresh, usable table
     T2 = DB["t_del"]
     assert T2.nnz() == 0
-
-
-def test_legacy_engine_still_works_and_flushes_lazily():
-    st = ShardedTable("legacy", num_shards=2, capacity_per_shard=2048,
-                      batch_cap=256, id_capacity=1 << 10, engine="single")
-    st.insert(np.asarray([1, 600], np.int32), np.asarray([0, 0], np.int32),
-              np.asarray([1.0, 2.0], np.float32))
-    st.flush()
-    assert st._mem_n.max() == 0
-    mem_before = st._mem_n.copy()
-    r, c, v = st.query_rows(np.asarray([1], np.int32))
-    assert len(r) == 1 and (st._mem_n == mem_before).all()
 
 
 # ------------------------------------------------------------ SPMD L0 path

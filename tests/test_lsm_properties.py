@@ -1,19 +1,19 @@
-"""Differential property tests for the storage engines.
+"""Differential property tests for the storage engine.
 
 Random interleavings of ingest/flush/compact/query/scan are applied to
-THREE readers of the same logical table — the LSM engine's fused
-single-dispatch read path, its per-run baseline path, and the legacy
-single-run engine — plus a sequential dict oracle; all four must agree
-for every combiner. Range scans are additionally checked against id-list
-point expansion of the same range (the pre-scan read path).
-``FUZZ_BUDGET`` (env, CI's weekly deep lane) adds that many extra
-examples per property.
+the LSM engine and to a sequential dict oracle (the reference); the
+fused point query, the fused range scan and the full scan must agree
+with the oracle for every combiner. Range scans are additionally checked
+against id-list point expansion of the same range (a second procedure
+over the same read path). ``FUZZ_BUDGET`` (env, CI's weekly deep lane)
+adds that many extra examples per property.
 
 Also home to the fused read paths' structural guarantees: the
 one-dispatch assertions for point queries AND range scans (memtable + L0
 runs + leveled runs answered by exactly one compiled-function invocation,
-every other entry point poisoned) and the batched Pallas rank kernel's
-equivalence to its reference.
+every other entry point poisoned), reads of a memtable whose host mirror
+is stale, and the batched Pallas rank kernel's equivalence to its
+reference.
 """
 import os
 
@@ -36,11 +36,6 @@ FUZZ_BUDGET = int(os.environ.get("FUZZ_BUDGET", "0"))
 # examples, so each draw costs milliseconds, not a recompile
 CFG = dict(num_shards=2, capacity_per_shard=2048, batch_cap=256,
            id_capacity=1 << 8, memtable_cap=32, l0_slots=3)
-
-
-def _mk(engine, fused):
-    return ShardedTable(f"prop_{engine}_{fused}", engine=engine,
-                        fused_reads=fused, combiner=_mk.combiner, **CFG)
 
 
 def _oracle_apply(oracle, r, c, v, combiner):
@@ -73,15 +68,11 @@ def _check_close(got, want, label, ctx):
                                  "query", "scan"]), min_size=4, max_size=12))
 def test_engines_and_read_paths_agree(seed, combiner, ops):
     """insert/flush/compact in random order; every query op must return
-    identical results from the fused LSM path, the per-run LSM path, the
-    legacy engine, and the oracle — and every scan op must return the
-    same range from the fused scan, id-list point expansion, the
-    full-scan-filter baseline, the legacy engine, and the oracle. Ends
-    with a full-scan comparison."""
+    the oracle's answer from the fused point query, and every scan op
+    the oracle's range from both the fused scan and id-list point
+    expansion. Ends with a full-scan comparison."""
     rng = np.random.default_rng(seed)
-    _mk.combiner = combiner
-    lsm = _mk("lsm", True)          # one LSM store, two read procedures
-    single = _mk("single", False)
+    lsm = ShardedTable("prop_lsm", combiner=combiner, **CFG)
     oracle = {}
 
     def check_query():
@@ -92,15 +83,8 @@ def test_engines_and_read_paths_agree(seed, combiner, ops):
         absent = rng.integers(0, CFG["id_capacity"], 3).astype(np.int32)
         q = np.unique(np.concatenate([pick, absent])).astype(np.int32)
         want = {k: v for k, v in oracle.items() if k[0] in set(q.tolist())}
-        lsm.fused_reads = True
         fused = _as_dict(*lsm.query_rows(q))
-        lsm.fused_reads = False
-        perrun = _as_dict(*lsm.query_rows(q))
-        lsm.fused_reads = True
-        legacy = _as_dict(*single.query_rows(q))
         _check_close(fused, want, "fused", (seed, combiner))
-        _check_close(perrun, want, "per-run", (seed, combiner))
-        _check_close(legacy, want, "single-engine", (seed, combiner))
 
     def check_scan():
         # random [lo, hi): sometimes empty (hi == lo), sometimes past the
@@ -110,19 +94,12 @@ def test_engines_and_read_paths_agree(seed, combiner, ops):
         hi = min(lo + int(rng.integers(0, 64)), CFG["id_capacity"] + 4)
         want = {k: v for k, v in oracle.items() if lo <= k[0] < hi}
         ctx = (seed, combiner, lo, hi)
-        lsm.fused_reads = True
         fused = _as_dict(*lsm.scan_range(lo, hi))
         # id-list point expansion of the same range (the pre-scan path)
         ids = np.arange(lo, min(hi, CFG["id_capacity"]), dtype=np.int32)
         expanded = _as_dict(*lsm.query_rows(ids)) if len(ids) else {}
-        lsm.fused_reads = False
-        filtered = _as_dict(*lsm.scan_range(lo, hi))  # full-scan baseline
-        lsm.fused_reads = True
-        legacy = _as_dict(*single.scan_range(lo, hi))
         _check_close(fused, want, "fused-scan", ctx)
         _check_close(expanded, want, "point-expansion", ctx)
-        _check_close(filtered, want, "scan-filter-baseline", ctx)
-        _check_close(legacy, want, "single-engine-scan", ctx)
 
     for op in ops:
         if op == "ins":
@@ -133,14 +110,11 @@ def test_engines_and_read_paths_agree(seed, combiner, ops):
                  if combiner == "sum" else
                  rng.normal(size=n).astype(np.float32))
             lsm.insert(r, c, v)
-            single.insert(r, c, v)
             _oracle_apply(oracle, r, c, v, combiner)
         elif op == "flush":
             lsm.flush()
-            single.flush()
         elif op == "compact":
             lsm.major_compact()
-            single.flush()  # legacy engine has no compaction
         elif op == "scan":
             check_scan()
         else:
@@ -163,8 +137,8 @@ def test_fused_point_query_is_one_dispatch(monkeypatch):
     """The acceptance bar: a point query against a shard holding a
     non-empty memtable, >=2 L0 runs, and >=2 leveled runs runs exactly ONE
     compiled-function invocation — counted via the obs registry's
-    dispatch counter, with every other query entry point poisoned so a
-    stray per-run launch fails loudly."""
+    dispatch counter, with the scan entry points poisoned so a point
+    query answered by a scan fails loudly."""
     st_ = ShardedTable("one_dispatch", num_shards=1,
                        capacity_per_shard=4096, batch_cap=256,
                        id_capacity=1 << 10, combiner="sum",
@@ -197,11 +171,11 @@ def test_fused_point_query_is_one_dispatch(monkeypatch):
     put(20, 800)             # non-empty memtable tail
     assert int(st_._runs.l0_used[0]) >= 2 and int(st_._mem_n[0]) > 0
 
-    # poison every non-fused query entry point
+    # poison every other read entry point
     def boom(*a, **k):
-        raise AssertionError("non-fused query path was dispatched")
-    monkeypatch.setattr(lsm_engine, "run_query_gated", boom)
-    monkeypatch.setattr(lsm_engine, "run_query_rows", boom)
+        raise AssertionError("a scan path answered a point query")
+    monkeypatch.setattr(lsm_engine.LSMRuns, "scan_shard_fused", boom)
+    monkeypatch.setattr(lsm_engine.LSMRuns, "scan_shard", boom)
 
     keys = np.asarray(sorted({k[0] for k in oracle}), np.int32)
     q = rng.choice(keys, 8, replace=False).astype(np.int32)
@@ -225,8 +199,8 @@ def test_fused_range_scan_is_one_dispatch(monkeypatch):
     """The scan acceptance bar: a [lo, hi) range scan against a shard
     holding a non-empty memtable, >=2 L0 runs, and >=2 leveled runs runs
     exactly ONE compiled-function invocation — counted via the engine's
-    scan-dispatch counter, with the point-query entry points (fused AND
-    per-run) poisoned so any id-list point expansion fails loudly."""
+    scan-dispatch counter, with the point-query and full-scan entry
+    points poisoned so any id-list point expansion fails loudly."""
     st_ = ShardedTable("one_scan", num_shards=1,
                        capacity_per_shard=4096, batch_cap=256,
                        id_capacity=1 << 10, combiner="sum",
@@ -258,14 +232,12 @@ def test_fused_range_scan_is_one_dispatch(monkeypatch):
     put(20, 800)             # non-empty memtable tail
     assert int(st_._runs.l0_used[0]) >= 2 and int(st_._mem_n[0]) > 0
 
-    # poison EVERY point-query entry point: the scan must not expand the
-    # range into point reads, fused or otherwise
+    # poison every other read entry point: the scan must not expand the
+    # range into point reads or filter a full scan
     def boom(*a, **k):
         raise AssertionError("point-query path was dispatched for a scan")
-    monkeypatch.setattr(lsm_engine, "run_query_gated", boom)
-    monkeypatch.setattr(lsm_engine, "run_query_rows", boom)
     monkeypatch.setattr(lsm_engine.LSMRuns, "query_shard_fused", boom)
-    monkeypatch.setattr(lsm_engine.LSMRuns, "query_shard", boom)
+    monkeypatch.setattr(lsm_engine.LSMRuns, "scan_shard", boom)
 
     lo, hi = 150, 700        # spans both levels, both L0 runs
     before = _ctr("lsm_scan_dispatches", "one_scan")
@@ -290,18 +262,14 @@ def test_fused_range_scan_is_one_dispatch(monkeypatch):
 
 
 def test_tiled_large_batch_matches_all_paths():
-    """Batches far above ``fused_q_limit`` stay on the fused path, split
-    into query tiles: exactly ceil(unique/tile) dispatches per shard
-    (plus any widen retries), ``fused_tiles`` accounting for the split,
-    ZERO per-run launches — and results identical to the per-run
-    baseline, the legacy engine, and the oracle, with duplicate query ids
-    re-expanded."""
+    """Batches far above ``fused_q_limit`` are split into query tiles:
+    exactly ceil(unique/tile) dispatches per shard (plus any widen
+    retries), ``fused_tiles`` accounting for the split — and results
+    identical to the oracle, with duplicate query ids re-expanded."""
     tile = 32
     mk = dict(num_shards=2, capacity_per_shard=4096, batch_cap=256,
               id_capacity=1 << 10, combiner="sum", memtable_cap=64)
-    lsm = ShardedTable("tiled_lsm", engine="lsm", l0_slots=3,
-                       fused_q_limit=tile, **mk)
-    single = ShardedTable("tiled_single", engine="single", **mk)
+    lsm = ShardedTable("tiled_lsm", l0_slots=3, fused_q_limit=tile, **mk)
     rng = np.random.default_rng(7)
     oracle = {}
 
@@ -311,7 +279,6 @@ def test_tiled_large_batch_matches_all_paths():
         c = rng.integers(0, 4, 48).astype(np.int32)
         v = rng.integers(-4, 5, 48).astype(np.float32)
         lsm.insert(r, c, v)
-        single.insert(r, c, v)
         _oracle_apply(oracle, r, c, v, "sum")
         if i in (0, 2, 3):
             lsm.flush()
@@ -334,8 +301,7 @@ def test_tiled_large_batch_matches_all_paths():
     assert exp_tiles >= 4, exp_tiles  # the batch genuinely tiles
 
     def deltas(fn):
-        names = ("fused_dispatches", "fused_widen_retries", "fused_tiles",
-                 "perrun_dispatches")
+        names = ("fused_dispatches", "fused_widen_retries", "fused_tiles")
         b = {n: _ctr("lsm_" + n, "tiled_lsm") for n in names}
         out = fn()
         return out, {n: _ctr("lsm_" + n, "tiled_lsm") - b[n] for n in names}
@@ -345,13 +311,6 @@ def test_tiled_large_batch_matches_all_paths():
     assert d["fused_dispatches"] == exp_disp + d["fused_widen_retries"], \
         (d, exp_disp)
     assert d["fused_tiles"] == exp_tiles, (d, exp_tiles)
-    assert d["perrun_dispatches"] == 0, d  # the fallback is retired
-
-    lsm.fused_reads = False
-    (pr, pc, pv), d_pr = deltas(lambda: lsm.query_rows(q))
-    lsm.fused_reads = True
-    assert d_pr["fused_dispatches"] == 0 and d_pr["perrun_dispatches"] > 0
-    sr, sc, sv = single.query_rows(q)
 
     want_r, want_c, want_v = [], [], []
     by_row: dict = {}
@@ -370,14 +329,65 @@ def test_tiled_large_batch_matches_all_paths():
         return r[order], c[order], v[order]
 
     want = norm(want_r, want_c, want_v)
-    for label, got in (("tiled-fused", (fr, fc, fv)),
-                       ("per-run", (pr, pc, pv)),
-                       ("single-engine", (sr, sc, sv))):
-        gr, gc, gv = norm(*got)
-        np.testing.assert_array_equal(gr, want[0], err_msg=label)
-        np.testing.assert_array_equal(gc, want[1], err_msg=label)
-        np.testing.assert_allclose(gv, want[2], rtol=1e-5, atol=1e-6,
-                                   err_msg=label)
+    gr, gc, gv = norm(fr, fc, fv)
+    np.testing.assert_array_equal(gr, want[0])
+    np.testing.assert_array_equal(gc, want[1])
+    np.testing.assert_allclose(gv, want[2], rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("with_filter", [False, True],
+                         ids=["all_cols", "col_filter"])
+@pytest.mark.parametrize("read", ["query_rows", "scan_range"])
+def test_stale_mirror_reads_match_oracle(read, with_filter):
+    """``insert_routed`` appends on the device and leaves the host mirror
+    of the memtable stale: the fused read then takes the tail as device
+    slices and sorts it inside its dispatch. Point queries and range
+    scans, with and without a column filter, must equal the oracle
+    across leveled runs, an L0 run and that tail, and flush nothing."""
+    st_ = ShardedTable("stale_" + read + str(with_filter), num_shards=2,
+                       capacity_per_shard=2048, batch_cap=128,
+                       id_capacity=1 << 8, combiner="last",
+                       memtable_cap=64, l0_slots=3)
+    rng = np.random.default_rng(11)
+    oracle = {}
+
+    def batch(n):
+        r = rng.integers(0, 1 << 8, n).astype(np.int32)
+        c = rng.integers(0, 6, n).astype(np.int32)
+        v = rng.normal(size=n).astype(np.float32)
+        _oracle_apply(oracle, r, c, v, "last")
+        return r, c, v
+
+    for _ in range(4):       # a leveled run
+        st_.insert(*batch(40))
+        st_.flush()
+    st_.major_compact()
+    st_.insert(*batch(40))   # an L0 run
+    st_.flush()
+    st_.insert(*batch(20))   # a tail the mirror holds ...
+    st_.insert_routed(*st_.route(*batch(30)))   # ... then made stale
+    assert not st_._mirror_ok and int(st_._mem_n.sum()) == 50
+    mem_n0 = st_._mem_n.copy()
+    flushes0 = st_.engine_stats()["flushes"]
+
+    cf = np.asarray([0, 2, 3], np.int32) if with_filter else None
+    keep_col = (lambda b: b in {0, 2, 3}) if with_filter else (lambda b: True)
+    if read == "query_rows":
+        q = np.unique(rng.integers(0, 1 << 8, 60)).astype(np.int32)
+        got = _as_dict(*st_.query_rows(q, col_filter=cf))
+        sel = set(q.tolist())
+        want = {k: x for k, x in oracle.items()
+                if k[0] in sel and keep_col(k[1])}
+    else:
+        lo, hi = 40, 200     # spans both shards
+        got = _as_dict(*st_.scan_range(lo, hi, col_filter=cf))
+        want = {k: x for k, x in oracle.items()
+                if lo <= k[0] < hi and keep_col(k[1])}
+    assert want  # the reads have something to find
+    _check_close(got, want, "stale-mirror-" + read, with_filter)
+    # the reads flushed nothing and left the tail where it was
+    np.testing.assert_array_equal(st_._mem_n, mem_n0)
+    assert st_.engine_stats()["flushes"] == flushes0
 
 
 def test_empty_shard_fused_query_observes_latency():

@@ -1,7 +1,7 @@
 """Observability layer tests (ISSUE 6): histogram quantile accuracy vs a
 numpy oracle, labeled-series aggregation, cross-process snapshot merging,
 span nesting/ring eviction/slow-op capture, Chrome-trace export shape,
-engine counter-schema parity, DBserver.metrics(), and the disabled-mode
+the engine counter schema, DBserver.metrics(), and the disabled-mode
 overhead budget (instrumentation must cost <2% of a query when off)."""
 import json
 import math
@@ -423,8 +423,8 @@ _CFG = dict(num_shards=2, capacity_per_shard=2048, batch_cap=256,
             id_capacity=1 << 10, memtable_cap=64, l0_slots=4)
 
 
-def _tiny(name, engine):
-    st = ShardedTable(name, engine=engine, **_CFG)
+def _tiny(name):
+    st = ShardedTable(name, **_CFG)
     rng = np.random.default_rng(5)
     r = rng.integers(0, 1 << 10, 200).astype(np.int32)
     for i in range(0, 200, 50):           # memtable cap is 64
@@ -434,27 +434,58 @@ def _tiny(name, engine):
     return st, r
 
 
-def test_engine_stats_schema_parity_single_vs_lsm():
-    """The single-run engine must emit the same counter schema as the LSM
-    engine — zeros where the op doesn't apply — so dashboards and
-    DBserver.metrics() don't special-case the engine."""
-    lsm, r = _tiny("par_lsm", "lsm")
-    single, _ = _tiny("par_single", "single")
-    ks, kl = lsm.engine_stats(), single.engine_stats()
-    assert set(ks) == set(kl)
-    for k in ("fused_dispatches", "scan_dispatches", "runs_probed",
-              "major_compactions"):
-        assert kl[k] == 0, k              # structurally n/a -> zero
-    assert kl["flushes"] >= 1 and ks["flushes"] >= 1
-    q = np.unique(r[:8])
-    lsm.query_rows(q)
-    single.query_rows(q)
-    assert lsm.engine_stats()["fused_dispatches"] >= 1
-    assert single.engine_stats()["fused_dispatches"] == 0
+@pytest.mark.parametrize("table", ["plain", "pair_sibling"])
+def test_engine_stats_schema(table):
+    """``engine_stats()`` is exactly the engine's counter schema plus the
+    two geometry keys — on a plain table and on a transpose pair's
+    sibling — and the registry holds no per-table ``lsm_*`` counter
+    outside that schema and the engine's write/tablet counters."""
+    from repro.db.lsm.engine import STAT_KEYS
+    assert STAT_KEYS == ("flushes", "major_compactions", "runs_probed",
+                         "runs_skipped", "fused_dispatches",
+                         "fused_widen_retries", "fused_tiles",
+                         "scan_dispatches", "scan_widen_retries")
+    name = "schema_" + table
+    st = ShardedTable(name, transpose=(table == "pair_sibling"), **_CFG)
+    st.insert(np.arange(50, dtype=np.int32), np.zeros(50, np.int32),
+              np.ones(50, np.float32))
+    target = st.t_store if table == "pair_sibling" else st
+    target.query_rows(np.arange(8, dtype=np.int32))
+    target.scan_range(0, 32)
+    stats = target.engine_stats()
+    assert set(stats) == set(STAT_KEYS) | {"l0_used", "level_entries"}
+    assert len(stats["l0_used"]) == _CFG["num_shards"]
+    assert stats["fused_dispatches"] + stats["scan_dispatches"] >= 1
+    table_ctrs = {i.name for i in default_registry().series(
+                      table=target.name)
+                  if i.kind == "counter" and set(i.labels) == {"table"}
+                  and i.name.startswith("lsm_")}
+    assert table_ctrs == {"lsm_" + k for k in STAT_KEYS} | {
+        "lsm_flush_entries", "lsm_compact_entries",
+        "lsm_compact_skipped_shards", "lsm_tablet_splits",
+        "lsm_tablet_moves", "lsm_tablet_merges"}
+    st.close()
+
+
+def test_metrics_and_debug_bundle_report_the_lsm_engine(tmp_path):
+    """The operator-facing formats keep their ``"engine": "lsm"`` key."""
+    import zipfile
+    DB = dbsetup("engdb", dict(num_shards=2, capacity_per_shard=4096,
+                               batch_cap=2048, id_capacity=1 << 16))
+    T = DB["etab"]
+    T.put_triple(np.asarray(["a", "b"], object), np.asarray(["x", "y"], object),
+                 np.asarray([1.0, 2.0]))
+    assert DB.metrics()["tables"]["etab"]["engine"] == "lsm"
+    path = DB.debug_bundle(str(tmp_path / "b.zip"))
+    with zipfile.ZipFile(path) as zf:
+        geo = json.loads(zf.read("resident_geometry.json"))
+        cfg = json.loads(zf.read("store_config.json"))
+    assert geo["etab"]["engine"] == "lsm"
+    assert (cfg["engine"], cfg["fused_reads"]) == ("lsm", True)
 
 
 def test_ingest_and_query_series_land_in_registry():
-    st, r = _tiny("obs_tab", "lsm")
+    st, r = _tiny("obs_tab")
     reg = default_registry()
     per_shard = sum(c.value for c in reg.series("db_ingest_entries",
                                                 table="obs_tab"))
@@ -511,7 +542,7 @@ def test_disabled_mode_overhead_budget():
     ``Histogram.observe`` AFTER the ``enabled`` early-return — so the
     disabled per-op costs measured below are the true all-in costs of the
     PR-9 instrumentation, not a subset."""
-    st, r = _tiny("ovh_tab", "lsm")
+    st, r = _tiny("ovh_tab")
     st.insert(r[:32], np.zeros(32, np.int32), np.ones(32, np.float32))
     q = np.unique(r[:8])
     st.query_rows(q)                      # warm the jit cache

@@ -165,9 +165,8 @@ def test_dbserver_debug_bundle_archive(tmp_path):
         assert "btab" in geo
         g = geo["btab"]
         assert g["num_shards"] == 2 and len(g["memtable_n"]) == 2
-        assert g["engine"] in ("single", "lsm")
-        if g["engine"] == "lsm":
-            assert len(g["resident_runs"]) == 2
+        assert g["engine"] == "lsm"
+        assert len(g["resident_runs"]) == 2
         view = json.loads(zf.read("metrics_view.json"))
         assert view["instance"] == "bundledb"
         assert "health" in view["tables"]["btab"]

@@ -12,7 +12,8 @@
   ``put`` ingests once (engine dual-writes), checkpoint/recover restore
   both sides from one snapshot + pair-tagged WAL, ``delete``/``drop``
   release the store (leak regression).
-* ``ReadPlan`` / ``StoreConfig`` round-trips and the deprecated
+* ``ReadPlan`` / ``StoreConfig`` round-trips (manifests as older
+  versions wrote them, removed options rejected) and the deprecated
   ``resolve_selector`` shim.
 """
 import dataclasses
@@ -358,8 +359,67 @@ def test_store_config_roundtrip_and_overrides():
         DB.config.replace(not_a_field=1)
 
 
-def test_transpose_requires_lsm_engine():
-    with pytest.raises(ValueError, match="lsm"):
-        ShardedTable("bad", engine="single", transpose=True,
-                     num_shards=1, capacity_per_shard=512,
-                     batch_cap=64, id_capacity=1 << 8)
+# a manifest config as ``lsm.manifest.write_snapshot`` writes it: the
+# StoreConfig fields plus the per-table extras stored alongside
+_PARENT_MANIFEST = {
+    "num_shards": 4, "capacity_per_shard": 21810380, "batch_cap": 32768,
+    "id_capacity": 4194304, "use_pallas": False, "engine": "lsm",
+    "fused_reads": True, "fused_q_limit": 512, "l0_slots": 4, "fanout": 4,
+    "memtable_cap": None, "transpose": True, "dynamic_tablets": False,
+    "combiner": "last", "mem_cap": 262144, "bloom_bits_per_key": [10],
+    "bloom_hashes": [7]}
+
+
+@pytest.mark.parametrize("case", ["parent", "engine_single",
+                                  "fused_reads_false"])
+def test_store_config_from_parent_manifest(case):
+    """A manifest written before the single-run engine and the per-run
+    read path were removed still loads: ``engine: "lsm"`` and
+    ``fused_reads: true`` are accepted, the per-table extras are ignored,
+    and the same config comes back; a manifest naming a removed option
+    raises."""
+    man = dict(_PARENT_MANIFEST)
+    if case == "parent":
+        assert StoreConfig.from_manifest(man) == StoreConfig(
+            capacity_per_shard=21810380, transpose=True)
+        return
+    key, bad = (("engine", "single") if case == "engine_single"
+                else ("fused_reads", False))
+    man[key] = bad
+    with pytest.raises(ValueError, match=key):
+        StoreConfig.from_manifest(man)
+
+
+_REMOVED = {"engine_single": ({"engine": "single"}, "engine='lsm'"),
+            "fused_reads_false": ({"fused_reads": False},
+                                  "fused_reads=True"),
+            "fused_q_limit_none": ({"fused_q_limit": None}, "positive int"),
+            "fused_q_limit_zero": ({"fused_q_limit": 0}, "positive int")}
+_LAYERS = {
+    "StoreConfig": lambda kw: StoreConfig(**kw),
+    "dbsetup": lambda kw: dbsetup("removed", dict(num_shards=1, **kw)),
+    "ShardedTable": lambda kw: ShardedTable(
+        "removed", num_shards=1, capacity_per_shard=512, batch_cap=64,
+        id_capacity=1 << 8, transpose=True, **kw),
+}
+
+
+@pytest.mark.parametrize("layer,option", [
+    (lay, o) for lay in _LAYERS for o in _REMOVED
+    # a None keyword of ShardedTable means "take it from the config"
+    if not (lay == "ShardedTable" and o == "fused_q_limit_none")])
+def test_removed_options_are_rejected(layer, option):
+    """The single-run engine, the per-run read path and untiled fused
+    reads are gone: asking for one raises ValueError naming what
+    remains, at every layer a config is built."""
+    kw, remains = _REMOVED[option]
+    with pytest.raises(ValueError, match=remains):
+        _LAYERS[layer](kw)
+
+
+def test_sharded_table_none_keyword_takes_the_config_value():
+    st = ShardedTable("inherit", num_shards=1, capacity_per_shard=512,
+                      batch_cap=64, id_capacity=1 << 8, fused_q_limit=None,
+                      config=StoreConfig(fused_q_limit=64))
+    assert st.fused_q_limit == 64 and st.config.fused_q_limit == 64
+    st.close()
